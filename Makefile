@@ -11,8 +11,8 @@
 #   make test        full tier-1 (slow + concurrency included)
 #   make bench       the full benchmark sweep (writes BENCH_*.json)
 #   make bench-codec the codec hot-path sweep alone (BENCH_codec_throughput.json)
-#   make bench-kernels the device-kernel parity gate + accelerator sweeps
-#                    (BENCH_kernel_codec.json; timings SKIP on CPU hosts)
+#   make bench-kernels the device-kernel parity gate + sweeps, on a chip
+#                    only (BENCH_kernel_codec.json; raises on a CPU host)
 #   make obs-smoke   REPRO_OBS=0 codec overhead guard (scripts/obs_smoke.py)
 #   make gateway-smoke spawn a gateway subprocess, drive concurrent socket
 #                    clients, assert latency percentiles + SIGTERM drain

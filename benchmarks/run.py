@@ -27,7 +27,6 @@ MODULES = [
     ("gateway_throughput", "gateway_throughput"),
     ("dist_grad_compress", "grad_compress"),
     ("codec_throughput", "codec_throughput"),
-    ("kernel_codec", "kernel_throughput"),
     ("obs_overhead", "obs_overhead"),
 ]
 

@@ -42,7 +42,10 @@ so a failing run replays exactly.
     PYTHONPATH=src python scripts/chaos.py --smoke --seed 0  # ~30s gate
     make chaos                                               # seeds 0-4
 
-Needs only the stdlib + the repo (jax-free, like the gateway launcher).
+Needs only the stdlib + the repo.  This parent process never asks JAX
+for a device, and each child names its platform (``_ROLE_PLATFORM``):
+on a TPU host only the writer opens the chip, and the standby opens it
+only after taking the lease from the killed writer.
 """
 
 from __future__ import annotations
@@ -143,11 +146,17 @@ class Proc:
         self._logf.close()
 
 
+#: one process per chip: the writer (and the standby once it holds the
+#: lease) keep JAX's choice; the replica stays on the host
+_ROLE_PLATFORM = {"writer": "auto", "standby": "auto", "replica": "cpu"}
+
+
 def _spawn(name: str, role: str, store: Path, port_file: Path, spec: str,
            seed: int, tmp: Path, *, scrub_s: float = 0.0,
            stats_json: Optional[Path] = None) -> Proc:
     cmd = [sys.executable, "-m", "repro.launch.gateway",
            "--store-dir", str(store), "--role", role,
+           "--platform", _ROLE_PLATFORM[role],
            "--port", "0", "--port-file", str(port_file),
            "--shards", "3", "--flush-batch", "8"]
     if scrub_s:

@@ -80,7 +80,7 @@ assert bytes(rans_decode_interleaved_device(
 print("kernel smoke: LZ77 + rANS device paths byte-identical (interpret mode)")
 PYEOF
 
-# Gateway smoke: spawn a real gateway subprocess (jax-free launcher),
+# Gateway smoke: spawn a real writer gateway subprocess,
 # drive it with concurrent socket clients, and require nonzero request-
 # latency percentiles in the obs snapshot, a graceful SIGTERM drain
 # (exit 0), and an atomically published --stats-json that parses.

@@ -6,8 +6,9 @@ graceful SIGTERM drain (exit 0), and an atomically published
 ``--stats-json`` that parses.
 
 Run by scripts/check.sh (and ``make gateway-smoke``); needs only the
-stdlib + the repo (the gateway launcher is deliberately jax-free, so
-this costs store-open time, not accelerator-import time).
+stdlib + the repo.  This parent never asks JAX for a device; the one
+child is a writer, which names its platform (``auto``: the chip when one
+is attached) — see ``repro.launch.gateway`` on one process per chip.
 """
 
 from __future__ import annotations
@@ -40,7 +41,8 @@ def main() -> int:
                                if env.get("PYTHONPATH") else "")
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.launch.gateway",
-         "--store-dir", str(tmp / "store"), "--build-corpus", "12",
+         "--store-dir", str(tmp / "store"), "--role", "writer",
+         "--platform", "auto", "--build-corpus", "12",
          "--port", "0", "--port-file", str(port_file),
          "--stats-json", str(stats_json), "--flush-batch", "8"],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)

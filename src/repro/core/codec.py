@@ -197,8 +197,8 @@ class Codec(Protocol):
 
 
 # device-packing crossover (total ids across the batch): one kernel
-# launch per width group still has to beat per-stream NumPy casts;
-# override with REPRO_PACK_DEVICE_MIN when re-tuning
+# launch per width group still has to beat per-stream NumPy casts.  An
+# estimate, not a chip measurement; override with REPRO_PACK_DEVICE_MIN
 _PACK_DEVICE_MIN_IDS = 1 << 14
 
 
@@ -233,14 +233,9 @@ class TokenPackCodec:
             if _device.use_device(total, "REPRO_PACK_DEVICE_MIN",
                                   _PACK_DEVICE_MIN_IDS,
                                   force=self.use_device):
-                import jax
-
                 from repro.kernels.token_pack import pack_fixed_batch_device
 
-                # compiled kernel on real accelerators; interpret mode only
-                # when the device path is forced on a CPU host (tests)
-                return pack_fixed_batch_device(
-                    ids_list, interpret=jax.default_backend() == "cpu")
+                return pack_fixed_batch_device(ids_list)
         return [packing.pack_tokens(ids, self.scheme) for ids in ids_list]
 
     def decode_ids_batch(self, payloads: Sequence[bytes],

@@ -9,30 +9,57 @@ questions before leaving the host:
    magnitude, so the device path is never taken implicitly there;
 2. is the payload big enough to amortize the host->device->host round
    trip?  Tiny payloads pay more in dispatch + transfer than the kernel
-   saves — each call site carries a measured crossover, overridable by
-   an env knob for re-tuning on new hardware.
+   saves — each call site carries a crossover, overridable by an env
+   knob.  The defaults are estimates, not chip measurements.
 
 Keeping the answers here (instead of one private helper per module, as
 the histogram and token-pack stages originally grew) means the routing
 policy is uniform and testable in one place.
+
+A device path that is taken runs the compiled kernel (see
+``repro.kernels.interpret_default``); an error there propagates — it is
+never turned into a host fallback.
+
+The module also places JAX's persistent compilation cache
+(:func:`enable_compile_cache`), which every entry point that compiles
+for the chip calls before its first compile.
 """
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Optional
 
 from repro import obs
 from repro.core import env
 
+#: the compile cache's home when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: fixed and inside the checkout (git-ignored), because the directory is
+#: part of the cache key — a path that moves never hits
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
 
 def backend_available() -> bool:
     """True iff JAX has a non-CPU backend attached."""
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend() != "cpu"
-    except Exception:  # pragma: no cover - jax is a hard dep of this repo
-        return False
+    return jax.default_backend() != "cpu"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left alone; otherwise the cache goes to :data:`DEFAULT_COMPILE_CACHE`.
+    Call before the first compile: JAX fixes the cache at that point."""
+    import jax
+
+    outside = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if outside:
+        return outside
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)
 
 
 def crossover(env_var: str, default: int) -> int:
@@ -40,7 +67,7 @@ def crossover(env_var: str, default: int) -> int:
 
     Reads ``env_var`` fresh on every call so benchmarks and tests can
     re-tune without reimporting; invalid values fall back to the
-    measured default rather than raising (the registry's int parser
+    call site's default rather than raising (the registry's int parser
     raises and ``env.read`` absorbs it into the default).
     """
     return env.read(env_var, default)

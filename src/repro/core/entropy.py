@@ -23,8 +23,8 @@ Data = Union[str, bytes]
 
 # device-histogram crossover (bytes): even with an accelerator attached,
 # small payloads pay more in upload + dispatch than the one-hot matmul
-# saves — measured on the kernel_throughput sweep; override with
-# REPRO_HIST_DEVICE_MIN when re-tuning on new hardware
+# saves.  An estimate, not yet measured on a chip (the sweep is
+# benchmarks/kernel_throughput.py); override with REPRO_HIST_DEVICE_MIN
 _DEVICE_MIN_BYTES = 1 << 15
 
 
@@ -44,14 +44,9 @@ def byte_histogram(data, use_device: Optional[bool] = None) -> np.ndarray:
 
     if _device.use_device(arr.size, "REPRO_HIST_DEVICE_MIN",
                           _DEVICE_MIN_BYTES, force=use_device) and arr.size:
-        import jax
-
         from repro.kernels.histogram import byte_histogram_device
 
-        # compiled kernel on real accelerators; interpret mode only when
-        # the device path is forced on a CPU host (tests, parity smokes)
-        return byte_histogram_device(
-            arr, interpret=jax.default_backend() == "cpu")
+        return byte_histogram_device(arr)
     return np.bincount(arr, minlength=256).astype(np.int64)
 
 

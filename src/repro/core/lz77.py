@@ -75,8 +75,8 @@ _EXT_ROUNDS = 3             # eager extension: 8-byte grams, cap 4+8*rounds
 _RUN_PROBE = 8192           # bytes sampled by the run-dominance probe
 _DEVICE_MIN_COMPRESS = 1 << 20   # auto-mode device crossover (bytes): the
                             # candidate stage must amortize the byte
-                            # upload + ok/cand/mlen download; override
-                            # with REPRO_LZ_DEVICE_MIN after re-measuring
+                            # upload + ok/cand/mlen download; an estimate,
+                            # not a chip measurement (REPRO_LZ_DEVICE_MIN)
 _DECODE_MAX_ROUNDS = 64     # frontier-batch rounds before python fallback
 
 # Seeded match tables per dictionary (scalar path): a dict-primed compress
@@ -672,8 +672,12 @@ def lz_compress(data: bytes, prefix: bytes = b"") -> bytes:
     payloads (zero pages, padding) stay scalar, where the skip-ahead
     loop is faster than any per-position vectorized scan.
     """
+    from repro.core import device as _device
+
     mode = _lz_mode()
-    if mode == "device":
+    if mode == "device":   # forced: still counted in the dispatch census
+        _device.use_device(len(data), "REPRO_LZ_DEVICE_MIN",
+                           _DEVICE_MIN_COMPRESS, force=True)
         return _lz_compress_device(data, prefix)
     if mode == "scalar" or (mode == "auto" and len(data) < _NP_MIN_COMPRESS):
         return _lz_compress_scalar(data, prefix)
@@ -681,8 +685,6 @@ def lz_compress(data: bytes, prefix: bytes = b"") -> bytes:
         probe = np.frombuffer(data[:_RUN_PROBE], np.uint8)
         if probe.size > 16 and float((probe[1:] == probe[:-1]).mean()) > 0.5:
             return _lz_compress_scalar(data, prefix)
-        from repro.core import device as _device
-
         if _device.use_device(len(data), "REPRO_LZ_DEVICE_MIN",
                               _DEVICE_MIN_COMPRESS):
             return _lz_compress_device(data, prefix)

@@ -57,8 +57,9 @@ _LANES_MIN_BYTES = 4096
 _LANES_MAX = 1024
 
 # auto-mode crossover for the device lane-parallel kernels: below this the
-# upload + per-call dispatch beats the lockstep win; override with
-# REPRO_RANS_DEVICE_MIN after re-measuring (benchmarks/kernel_throughput.py)
+# upload + per-call dispatch beats the lockstep win.  An estimate, not yet
+# measured on a chip (benchmarks/kernel_throughput.py); override with
+# REPRO_RANS_DEVICE_MIN
 _DEVICE_MIN_BYTES = 1 << 16
 
 
@@ -66,15 +67,15 @@ def _use_device_rans(n: int) -> bool:
     """REPRO_RANS_MODE routing: ``numpy`` forces the host coder,
     ``device`` forces the Pallas lane kernels (interpret mode on CPU —
     tests/parity smokes), ``auto`` (default) takes the device only when a
-    non-CPU backend is attached and the payload clears the crossover."""
-    mode = env.read("REPRO_RANS_MODE")
-    if mode == "device":
-        return True
-    if mode != "auto":
-        return False
+    non-CPU backend is attached and the payload clears the crossover.
+    Every decision, forced or not, lands in the ``device.dispatch``
+    census."""
     from repro.core import device as _device
 
-    return _device.use_device(n, "REPRO_RANS_DEVICE_MIN", _DEVICE_MIN_BYTES)
+    force = {"device": True, "auto": None}.get(env.read("REPRO_RANS_MODE"),
+                                               False)
+    return _device.use_device(n, "REPRO_RANS_DEVICE_MIN", _DEVICE_MIN_BYTES,
+                              force=force)
 
 
 def _env_lanes() -> Optional[int]:

@@ -3,18 +3,27 @@
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default, size_bucket
 from repro.kernels.histogram.kernel import histogram_kernel
+
+_HIST_PAD_MIN = 1024   # a kernel block (DEFAULT_BLOCK_N)
+
+
+def token_histogram(ids: jnp.ndarray, vocab_size: int,
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
+    """ids: [N] any int dtype -> counts [vocab_size] int32.
+    Pads N and vocab to kernel block multiples (pad ids are -1 = no bucket)."""
+    return _token_histogram(ids, vocab_size, interpret_default(interpret))
 
 
 @partial(jax.jit, static_argnames=("vocab_size", "interpret"))
-def token_histogram(ids: jnp.ndarray, vocab_size: int,
-                    interpret: bool = True) -> jnp.ndarray:
-    """ids: [N] any int dtype -> counts [vocab_size] int32.
-    Pads N and vocab to kernel block multiples (pad ids are -1 = no bucket)."""
+def _token_histogram(ids: jnp.ndarray, vocab_size: int,
+                     interpret: bool) -> jnp.ndarray:
     n = ids.shape[0]
     block_n = min(1024, max(n, 8))
     pad_n = (-n) % block_n
@@ -26,7 +35,7 @@ def token_histogram(ids: jnp.ndarray, vocab_size: int,
     return out[:vocab_size]
 
 
-def byte_histogram_device(data, interpret: bool = False):
+def byte_histogram_device(data, interpret: Optional[bool] = None):
     """256-bucket byte histogram on the accelerator — the rANS frequency
     table builder for device-resident entropy coding.  Accepts bytes or a
     uint8 ndarray; returns numpy int64 counts [256] (the shape
@@ -37,6 +46,9 @@ def byte_histogram_device(data, interpret: bool = False):
         else np.asarray(data, np.uint8)
     if arr.size == 0:
         return np.zeros(256, np.int64)
-    counts = token_histogram(jnp.asarray(arr, jnp.int32), 256,
-                             interpret=interpret)
+    # pad to a size bucket with -1 (no bucket) so payload lengths share
+    # compilations
+    ids = np.full(size_bucket(arr.size, _HIST_PAD_MIN), -1, np.int32)
+    ids[:arr.size] = arr
+    counts = token_histogram(jnp.asarray(ids), 256, interpret=interpret)
     return np.asarray(counts, dtype=np.int64)

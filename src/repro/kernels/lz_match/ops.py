@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_default, size_bucket
 from repro.kernels.lz_match.kernel import (gram_hash_kernel,
                                            match_extend_kernel)
 
@@ -52,20 +53,6 @@ _HASH_BITS = 20
 _SCAN_BLOCK = 1024
 _EXT_ROUNDS = 3
 _PAD_MIN = 16384   # must be a multiple of _SCAN_BLOCK and the kernel block
-
-
-def _interpret_default(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return jax.default_backend() == "cpu"
-    return interpret
-
-
-def _bucket(n: int) -> int:
-    """Pad target: next multiple of an eighth of the enclosing power of
-    two (>= _PAD_MIN) — bounds both pad waste (<12.5%) and the number of
-    distinct jit compilations (<= 8 per octave)."""
-    q = max(_PAD_MIN, 1 << max(int(n).bit_length() - 3, 0))
-    return max(-(-n // q) * q, _PAD_MIN)
 
 
 @partial(jax.jit, static_argnames=("p", "interpret"))
@@ -129,13 +116,13 @@ def lz_candidates_device(
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Device counterpart of ``lz77._candidates_np``: (ok bool[nv],
     cand intp[nv], mlen int64[nv]) for the full window+payload buffer."""
-    interpret = _interpret_default(interpret)
+    interpret = interpret_default(interpret)
     n = len(buf)
     nv = n - 3
     if nv <= 0:
         return (np.zeros(max(nv, 0), bool), np.zeros(max(nv, 0), np.intp),
                 np.zeros(max(nv, 0), np.int64))
-    p = _bucket(n)
+    p = size_bucket(n, _PAD_MIN)
     padded = np.zeros(p, np.uint8)
     padded[:n] = np.frombuffer(buf, np.uint8)
     ok, cand, mlen = _candidate_stage(

@@ -31,19 +31,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.rans_lanes.kernel import (DEFAULT_BLOCK_T,
+from repro.kernels import interpret_default
+from repro.kernels.rans_lanes.kernel import (DEFAULT_BLOCK_T, WORD_ROW,
+                                             decode_window_rows,
                                              rans_decode_lanes_kernel,
                                              rans_encode_lanes_kernel)
-
-_WORD_PAD = 1024   # word-stream padding granularity (bounds recompiles)
-
-
-def _interpret_default(interpret: Optional[bool]) -> bool:
-    # compiled kernel on real accelerators; interpret mode only when the
-    # device path is forced on a CPU host (tests, parity smokes)
-    if interpret is None:
-        return jax.default_backend() == "cpu"
-    return interpret
 
 
 @partial(jax.jit, static_argnames=("lanes", "prob_bits", "interpret"))
@@ -79,9 +71,9 @@ def _encode_stage(symbols: jnp.ndarray, freqs: jnp.ndarray, lanes: int,
     cs = jnp.pad(cs_all[: T * lanes].reshape(T, lanes),
                  ((0, tp - T), (0, 0)))
     words, emit, states = rans_encode_lanes_kernel(
-        fs, cs, x0, total_t=T, prob_bits=prob_bits, block_t=bt,
+        fs, cs, x0[None, :], total_t=T, prob_bits=prob_bits, block_t=bt,
         interpret=interpret)
-    return words, emit, states, tail_w, tail_em
+    return words, emit, states[0], tail_w, tail_em
 
 
 def rans_encode_interleaved_device(
@@ -91,7 +83,7 @@ def rans_encode_interleaved_device(
     """Device counterpart of ``rans_np.rans_encode_interleaved``: returns
     (words u16 in forward/decode order, final states [lanes] u32),
     bit-identical to the NumPy coder."""
-    interpret = _interpret_default(interpret)
+    interpret = interpret_default(interpret)
     n = int(symbols.size)
     words_d, emit_d, states_d, tail_w, tail_em = _encode_stage(
         jnp.asarray(symbols, jnp.uint8), jnp.asarray(freqs, jnp.uint32),
@@ -113,19 +105,23 @@ def _decode_stage(words: jnp.ndarray, states: jnp.ndarray,
                   interpret: bool):
     T = n // lanes
     rem = n - T * lanes
-    f32 = freqs.astype(jnp.uint32)
-    cum = jnp.cumsum(f32, dtype=jnp.uint32) - f32
-    s2s = jnp.repeat(jnp.arange(256, dtype=jnp.int32), f32,
-                     total_repeat_length=1 << prob_bits)
-    wp = max(-(-words.shape[0] // _WORD_PAD) * _WORD_PAD, _WORD_PAD)
-    wpad = jnp.pad(words.astype(jnp.uint32), (0, wp - words.shape[0]))
+    f32 = freqs.astype(jnp.int32)
+    cum = jnp.cumsum(f32) - f32                       # exclusive prefix
+    lp = -(-lanes // WORD_ROW) * WORD_ROW
+    rows = -(-words.shape[0] // WORD_ROW) + decode_window_rows(lp)
+    rows = -(-rows // 8) * 8
+    wpad = jnp.pad(words.astype(jnp.uint32),
+                   (0, rows * WORD_ROW - words.shape[0]))
+    st = jnp.pad(states.astype(jnp.uint32), (0, lp - lanes))
     sym, states_f, wcnt = rans_decode_lanes_kernel(
-        wpad, states.astype(jnp.uint32), f32, cum, s2s, total_t=T,
-        prob_bits=prob_bits, interpret=interpret)
-    flat = sym.reshape(-1)[: T * lanes]
+        wpad.reshape(rows, WORD_ROW), st[None, :], cum[:, None], total_t=T,
+        prob_bits=prob_bits, lanes=lanes, interpret=interpret)
+    flat = sym[:, :lanes].reshape(-1)[: T * lanes]
     if rem:   # tail symbols: slot lookup only, no renorm (mirrors NumPy)
-        slot = states_f[:rem] & jnp.uint32((1 << prob_bits) - 1)
-        flat = jnp.concatenate([flat, s2s[slot.astype(jnp.int32)]])
+        slot = (states_f[0, :rem] & jnp.uint32((1 << prob_bits) - 1)
+                ).astype(jnp.int32)
+        tail = jnp.sum(cum[None, :] <= slot[:, None], axis=1) - 1
+        flat = jnp.concatenate([flat, tail])
     return flat.astype(jnp.uint8), wcnt
 
 
@@ -138,7 +134,7 @@ def rans_decode_interleaved_device(
     ``to_host=False`` returns the uint8 symbol array still resident on
     the device (a jnp array) — the serve path hands it straight to the
     token-unpack stage without a host byte round trip."""
-    interpret = _interpret_default(interpret)
+    interpret = interpret_default(interpret)
     out, wcnt = _decode_stage(
         jnp.asarray(words, jnp.uint16), jnp.asarray(states, jnp.uint32),
         jnp.asarray(freqs, jnp.uint32), int(n), int(lanes),

@@ -8,12 +8,13 @@ bit-identical to repro.core.packing.pack_fixed, validated in tests.
 from __future__ import annotations
 
 from functools import partial
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_default
 from repro.kernels.token_pack.kernel import delta_zigzag_kernel, pack_tokens_kernel
 
 _BLOCK = 2048
@@ -29,17 +30,20 @@ def _pack_padded(ids: jnp.ndarray, width: int, interpret: bool) -> jnp.ndarray:
                               interpret=interpret)
 
 
-def pack_tokens_device(ids, interpret: bool = True) -> Tuple[int, bytes]:
+def pack_tokens_device(ids, interpret: Optional[bool] = None
+                       ) -> Tuple[int, bytes]:
     """Returns (format_byte, packed_bytes) per paper Algorithm 1 lines 2-8."""
     ids = np.asarray(ids, dtype=np.uint32)
     if ids.size == 0:
         return 0x00, b""
     width = 2 if int(ids.max()) <= 0xFFFF else 4
-    out = _pack_padded(jnp.asarray(ids, jnp.int32), width, interpret)
+    out = _pack_padded(jnp.asarray(ids, jnp.int32), width,
+                       interpret_default(interpret))
     return (0x00 if width == 2 else 0x01), np.asarray(out)[: ids.size].tobytes()
 
 
-def pack_fixed_batch_device(ids_list, interpret: bool = True) -> List[bytes]:
+def pack_fixed_batch_device(ids_list, interpret: Optional[bool] = None
+                            ) -> List[bytes]:
     """Batch fixed-width packing: the vectorized device path of the codec layer.
 
     Streams are grouped by packing width (Eq. 7 decides per stream), each
@@ -49,6 +53,7 @@ def pack_fixed_batch_device(ids_list, interpret: bool = True) -> List[bytes]:
     ``repro.core.packing.pack_fixed`` applied per stream (format byte
     included), which the kernel parity tests assert.
     """
+    interpret = interpret_default(interpret)
     arrs = [np.asarray(ids, dtype=np.uint32) for ids in ids_list]
     out: List[bytes] = [b""] * len(arrs)
     groups: dict = {2: [], 4: []}
@@ -94,9 +99,14 @@ def unpack_fixed_device(payload) -> jnp.ndarray:
     raise ValueError(f"format {fmt:#x} has no device unpacker")
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def delta_zigzag_device(ids: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
+def delta_zigzag_device(ids: jnp.ndarray,
+                        interpret: Optional[bool] = None) -> jnp.ndarray:
     """[N] ids -> [N,4] zigzag-delta bytes (feeder for the rANS stage)."""
+    return _delta_zigzag(ids, interpret_default(interpret))
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _delta_zigzag(ids: jnp.ndarray, interpret: bool) -> jnp.ndarray:
     prev = jnp.concatenate([jnp.zeros(1, ids.dtype), ids[:-1]])
     n = ids.shape[0]
     pad = (-n) % min(_BLOCK, max(n, 1))

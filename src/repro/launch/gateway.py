@@ -25,8 +25,24 @@ visible without any writer→replica channel.
 tmp+rename) once the socket is bound — how orchestration and tests
 discover an ephemeral ``--port 0``.  SIGTERM drains gracefully.
 
-Deliberately jax-free: a gateway process serves the store tier only, so
-it must start in store-open time, not accelerator-runtime-import time.
+One process per chip.  A chip belongs to one process at a time, and the
+codec reaches JAX on its own: a writer's group commit of
+``REPRO_PACK_DEVICE_MIN`` ids or more packs tokens with the Pallas
+kernel, and a multi-lane rANS blob may decode there.  So the platform is
+part of the role (``--platform``):
+
+* ``writer`` keeps JAX's own choice (``auto``: the accelerator when one
+  is attached) — it is the one process of the fleet that holds the chip;
+* ``replica`` defaults to ``cpu``: it serves reads on the host paths
+  and never opens the chip, however many replicas share the host;
+* ``standby`` keeps ``auto`` but touches no JAX backend while it waits:
+  the lease wait comes first, and nothing before it compiles or asks
+  for a device.  It opens the chip only after taking the lease, i.e.
+  after the writer died and the chip was released with its process.
+
+The platform is applied through ``jax.config`` before anything asks JAX
+for a backend; the writer and a standby that took over also place the
+persistent compile cache (``repro.core.device.enable_compile_cache``).
 """
 
 from __future__ import annotations
@@ -52,6 +68,11 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="store root shared by writer/standby/replicas")
     ap.add_argument("--role", choices=("writer", "standby", "replica"),
                     default="writer")
+    ap.add_argument("--platform", choices=("auto", "cpu"),
+                    default=None,
+                    help="JAX platform of this process: auto = JAX's own "
+                         "choice (default for writer and standby), cpu "
+                         "(default for replica: it never opens the chip)")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=0,
                     help="0 binds an ephemeral port (see --port-file)")
@@ -102,6 +123,8 @@ def parse_args(argv=None) -> argparse.Namespace:
     if args.build_corpus and args.role != "writer":
         ap.error("--build-corpus is writer-only: replicas and standbys "
                  "never mutate the store")
+    if args.platform is None:
+        args.platform = "cpu" if args.role == "replica" else "auto"
     for name in ("stats_interval", "cache_mb", "compact_interval",
                  "scrub_interval"):
         if getattr(args, name) < 0:
@@ -152,8 +175,18 @@ def _start_replica_refresher(store: ShardedPromptStore,
     return stop
 
 
+def pin_platform(platform: str) -> None:
+    """Apply the role's JAX platform before any backend exists
+    (``auto`` leaves JAX's own choice alone)."""
+    if platform != "auto":
+        import jax
+
+        jax.config.update("jax_platforms", platform)
+
+
 def main(argv=None) -> None:
     args = parse_args(argv)
+    pin_platform(args.platform)
     # leasing happens here: writer fails fast if owned, standby blocks
     # until takeover, replica never takes it
     if args.role == "standby" and args.lease_timeout is not None:
@@ -175,6 +208,10 @@ def main(argv=None) -> None:
     if args.role == "standby":
         print("[gateway] standby acquired the lease: taking over as writer",
               flush=True)
+    if not readonly:
+        from repro.core.device import enable_compile_cache
+
+        enable_compile_cache()
     if args.build_corpus:
         _seed_corpus(store, args.build_corpus, args.method)
     service = PromptService(

@@ -21,6 +21,7 @@ import time
 
 import jax
 
+from repro.core.device import enable_compile_cache
 from repro.data.pipeline import build_store_from_corpus
 from repro.launch.statsdump import start_stats_dumper, write_snapshot
 from repro.train.serve_loop import BatchServer
@@ -84,6 +85,7 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 def main(argv=None) -> None:
     args = parse_args(argv)
+    enable_compile_cache()
 
     from repro.configs.lopace import CONFIG
     from repro.service import PromptService

@@ -20,11 +20,13 @@ import argparse
 import tempfile
 import time
 from pathlib import Path
+from typing import List
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import ALIASES, get_config
+from repro.core.device import enable_compile_cache
 from repro.data.pipeline import PipelineConfig, TokenPipeline, build_store_from_corpus
 from repro.dist.checkpoint import (checkpoint_extra, checkpoint_step,
                                    latest_checkpoint, restore_checkpoint,
@@ -117,7 +119,8 @@ def _open_store(store_dir: Path, n_prompts: int):
     return store
 
 
-def run(args: argparse.Namespace, scratch: Path) -> None:
+def run(args: argparse.Namespace, scratch: Path) -> List[float]:
+    """Train; returns the loss of every step this launch ran."""
     if args.arch == "lopace":
         from repro.configs.lopace import CONFIG as cfg_full
     else:
@@ -152,12 +155,14 @@ def run(args: argparse.Namespace, scratch: Path) -> None:
         start = checkpoint_step(ck)
         print(f"[launch] resumed from step {start}")
 
+    losses = []
     for step in range(start, args.steps):
         t0 = time.perf_counter()
         batch = {k: jnp.asarray(v) for k, v in next(pipe).items()}
         if args.grad_accum > 1:
             batch = pipe.with_accum(batch, args.grad_accum)
         params, opt_state, m = step_fn(params, opt_state, batch)
+        losses.append(m["loss"])
         dt = time.perf_counter() - t0
         hb.beat(step, step_time_s=dt)
         if step % 10 == 0:
@@ -185,15 +190,17 @@ def run(args: argparse.Namespace, scratch: Path) -> None:
                             extra={"data": pipe.state()},
                             keep_last=args.keep_last)
     print("[launch] done")
+    return [float(x) for x in losses]
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> List[float]:
     args = parse_args(argv)
+    enable_compile_cache()
     # everything not explicitly pointed at a persistent path lives in one
     # run-scoped scratch dir and is removed on exit (the old mkdtemp
     # fallbacks leaked a store + heartbeat dir per launch)
     with tempfile.TemporaryDirectory(prefix="repro_train_") as scratch:
-        run(args, Path(scratch))
+        return run(args, Path(scratch))
 
 
 if __name__ == "__main__":
