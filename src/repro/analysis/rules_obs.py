@@ -15,7 +15,7 @@ Three checks keep the metric inventory coherent:
   both sites happen to run in one process; statically it is always a
   bug.  A span ``obs.span("x")`` owns the histogram name ``x.s``.
 * **No raw ``time.perf_counter`` timing in ``service/`` paths.**  The
-  service tier reports latency through ``obs.span`` (journal + duration
+  service tier reports latency through ``obs.span`` (trace event + duration
   histogram in one call); a bare perf_counter pair is dark telemetry.
   Waiverable as usual for timing that is genuinely not a metric.
 """
@@ -154,5 +154,5 @@ class MetricHygieneRule(Rule):
             findings.append(Finding(
                 RULE_ID, f.path, call.lineno,
                 "raw time.perf_counter timing in a service/ path bypasses "
-                "obs.span (no histogram, no journal event); wrap the block "
+                "obs.span (no histogram, no trace event); wrap the block "
                 "in obs.span or waive with a reason"))

@@ -117,10 +117,11 @@ def _parallel_map(fn: Callable[[bytes], bytes],
 # Every stage/pipeline owns a `_CodecObs` created once in __init__ —
 # the REPRO_OBS gate is resolved there, so the per-batch cost with obs
 # disabled is one perf_counter read and one no-op method call (byte
-# totals are only summed by the enabled twin).  Pipelines additionally
+# totals are only summed by the enabled twin; the token-pack stage's two
+# sub-spans read the clock only).  Pipelines additionally
 # export the paper's Table metrics as derived gauges: live compression
-# ratio and encode/decode MB/s per method, computed from the running
-# byte/second totals at snapshot time.
+# ratio and encode/decode MB/s (MB = 10**6 bytes, the paper's unit) per
+# method, computed from the running byte/second totals at snapshot time.
 
 
 class _CodecObs:
@@ -173,10 +174,10 @@ def _pipeline_obs(method: str):
             lambda: o.enc_in.value / o.enc_out.value, method=method)
         obs.derived_gauge(
             "codec.encode_mb_s",
-            lambda: (o.enc_in.value / 2**20) / o.enc_s.sum, method=method)
+            lambda: (o.enc_in.value / 1e6) / o.enc_s.sum, method=method)
         obs.derived_gauge(
             "codec.decode_mb_s",
-            lambda: (o.dec_out.value / 2**20) / o.dec_s.sum, method=method)
+            lambda: (o.dec_out.value / 1e6) / o.dec_s.sum, method=method)
     return o
 
 
@@ -264,8 +265,12 @@ class TokenPackCodec:
 
     def encode_batch(self, payloads: Sequence[bytes]) -> List[bytes]:
         t0 = time.perf_counter()
-        ids_list = self.tokenizer.encode_batch([p.decode("utf-8") for p in payloads])
-        out = self.encode_ids_batch([np.asarray(ids, np.uint32) for ids in ids_list])
+        with obs.span("codec.bpe.encode"):
+            ids_list = self.tokenizer.encode_batch(
+                [p.decode("utf-8") for p in payloads])
+        with obs.span("codec.pack.encode"):
+            out = self.encode_ids_batch(
+                [np.asarray(ids, np.uint32) for ids in ids_list])
         self._obs.encode(time.perf_counter() - t0, payloads, out)
         return out
 

@@ -22,12 +22,15 @@ never turned into a host fallback.
 
 The module also places JAX's persistent compilation cache
 (:func:`enable_compile_cache`), which every entry point that compiles
-for the chip calls before its first compile.
+for the chip calls before its first compile.  From the first routing
+decision that takes a device path on, it times every backend compile of
+the process into ``device.compile.s{fn=<jitted function>}``.
 """
 
 from __future__ import annotations
 
 import os
+import threading
 from pathlib import Path
 from typing import Optional
 
@@ -38,6 +41,12 @@ from repro.core import env
 #: fixed and inside the checkout (git-ignored), because the directory is
 #: part of the cache key — a path that moves never hits
 DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+#: JAX's event for one XLA backend compile (a persistent-cache hit skips it)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_compile_watch_lock = threading.Lock()
+_compile_watched = False
 
 
 def backend_available() -> bool:
@@ -73,6 +82,26 @@ def crossover(env_var: str, default: int) -> int:
     return env.read(env_var, default)
 
 
+def _record_compile(event: str, secs: float, fun_name: str = "unknown",
+                    **_) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        obs.histogram("device.compile.s", fn=fun_name).observe(secs)
+
+
+def _watch_compiles() -> None:
+    """Register the compile listener with JAX, once per process (JAX
+    keeps its listeners process-wide).  The ``fn`` label is bounded by
+    the number of jitted functions."""
+    global _compile_watched
+    with _compile_watch_lock:
+        if _compile_watched:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_record_compile)
+        _compile_watched = True
+
+
 def use_device(size: int, env_var: str, default_min: int,
                force: Optional[bool] = None) -> bool:
     """The standard routing decision: explicit ``force`` wins, otherwise
@@ -83,6 +112,8 @@ def use_device(size: int, env_var: str, default_min: int,
     else:
         decision = (backend_available()
                     and size >= crossover(env_var, default_min))
+    if decision and not _compile_watched:
+        _watch_compiles()
     # routing census: how often each kernel family actually leaves the
     # host (obs.counter is a no-op stub when REPRO_OBS=0)
     obs.counter("device.dispatch", knob=env_var.lower(),

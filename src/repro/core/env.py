@@ -190,9 +190,6 @@ declare("REPRO_ANALYSIS_FROZEN_MANIFEST", _parse_str, None,
 declare("REPRO_OBS", _parse_flag, True,
         "0/false disables the repro.obs metrics/tracing layer; "
         "instrument sites resolve to shared no-op stubs at creation")
-declare("REPRO_OBS_JOURNAL", _parse_int_min0, 4096,
-        "capacity (events) of the repro.obs span journal ring buffer; "
-        "oldest events are dropped first")
 
 
 def _parse_int_min1(raw: str) -> int:

@@ -50,7 +50,7 @@ RANKS: Dict[str, int] = {
     # out-rank every store lock; it stays below obs because _apply
     # records a metric, and obs never calls back into failpoints.
     "faults": 90,
-    # Leaf rank: repro.obs instrument/registry/journal locks.  Metrics
+    # Leaf rank: repro.obs instrument/registry locks.  Metrics
     # are recorded from inside every other critical section (a shard
     # append observes its fsync latency while the shard lock is held),
     # so obs locks must be acquirable while holding anything — and obs

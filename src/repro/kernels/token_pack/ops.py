@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import interpret_default
 from repro.kernels.token_pack.kernel import delta_zigzag_kernel, pack_tokens_kernel
 
@@ -67,9 +68,13 @@ def pack_fixed_batch_device(ids_list, interpret: Optional[bool] = None
             continue
         fmt = 0x00 if width == 2 else 0x01
         concat = np.concatenate([arrs[i] for i in members])
-        plane = np.asarray(
-            _pack_padded(jnp.asarray(concat, jnp.int32), width, interpret)
-        )[: concat.size]
+        # one launch per width group, read back to the host; its trace
+        # event carries the group's real (unpadded) id count
+        with obs.span("kernel.token_pack",
+                      trace_args={"ids": int(concat.size), "width": width}):
+            plane = np.asarray(
+                _pack_padded(jnp.asarray(concat, jnp.int32), width, interpret)
+            )[: concat.size]
         offsets = np.cumsum([0] + [arrs[i].size for i in members])
         for j, i in enumerate(members):
             out[i] = bytes([fmt]) + plane[offsets[j]:offsets[j + 1]].tobytes()

@@ -7,15 +7,19 @@ rule REPRO007 flags direct construction of the underlying classes):
   — get-or-create a shared instrument in the process-global registry.
 * ``obs.derived_gauge(name, fn, **labels)`` — a gauge whose value is
   computed at snapshot time (live compression ratio, MB/s).
-* ``obs.span(name, **labels)`` — context manager timing a block into a
-  ``<name>.s`` histogram plus the ring-buffer journal; usable as the
-  product's timing source via ``span.elapsed_s``/``span.duration_s``.
+* ``obs.span(name, trace_args=None, **labels)`` — context manager
+  timing a block into a ``<name>.s`` histogram and, while a JAX
+  profiler trace runs, into a trace event of the same name whose
+  metadata is the labels, ``trace_args`` and the thread's
+  ``obs.trace_context(**args)``; usable as the product's timing source
+  via ``span.elapsed_s``/``span.duration_s``.
 * ``obs.owned_counter(name, **labels)`` — an always-real counter owned
   by one component instance (``TokenCache`` hit/miss counts feed its
   ``stats()`` dict and must keep counting with obs disabled); it is
   *registered* into the global registry only when obs is enabled, with
   replace-on-reregister so snapshots follow the newest instance.
-* ``obs.snapshot()`` / ``obs.dump_journal(path)`` — export.
+* ``obs.snapshot()`` — export; the spans' events are in the profiler's
+  trace (:mod:`repro.obs.trace`).
 
 Disabled mode (``REPRO_OBS=0``): the factories return shared no-op
 stubs, resolved once at instrument creation — a disabled counter's
@@ -34,13 +38,12 @@ from repro.core import env
 from repro.obs import export as _export
 from repro.obs.metrics import (Counter, Gauge, Histogram, Registry,
                                canonical_name)
-from repro.obs.trace import Journal, NullSpan, Span
+from repro.obs.trace import NullSpan, Span, trace_context
 
 __all__ = [
     "enabled", "counter", "gauge", "derived_gauge", "histogram", "span",
     "owned_counter", "owned_gauge", "snapshot", "diff", "render",
-    "render_diff",
-    "dump_journal", "default_registry", "default_journal", "reset",
+    "render_diff", "trace_context", "default_registry", "reset",
 ]
 
 
@@ -93,28 +96,17 @@ NULL_GAUGE = _NullGauge()
 NULL_HISTOGRAM = _NullHistogram()
 
 _registry = Registry()
-_journal: Optional[Journal] = None
 
 
 def default_registry() -> Registry:
     return _registry
 
 
-def default_journal() -> Journal:
-    """The process journal; capacity is read from REPRO_OBS_JOURNAL at
-    first use (``reset()`` re-reads it)."""
-    global _journal
-    if _journal is None:
-        _journal = Journal(env.read("REPRO_OBS_JOURNAL"))
-    return _journal
-
-
 def reset() -> None:
-    """Fresh registry + journal (tests); instruments already handed out
-    keep working but stop appearing in snapshots."""
-    global _registry, _journal
+    """Fresh registry (tests); instruments already handed out keep
+    working but stop appearing in snapshots."""
+    global _registry
     _registry = Registry()
-    _journal = None
 
 
 def counter(name: str, **labels):
@@ -165,19 +157,19 @@ def owned_gauge(name: str, fn: Callable[[], float], **labels):
     return inst
 
 
-def span(name: str, **labels):
+def span(name: str, trace_args: Optional[Dict[str, Any]] = None,
+         **labels):
+    """Time a block into ``<name>.s{labels}``; ``trace_args`` go into
+    its trace event only (identifiers, sizes), never into the
+    histogram's name."""
     if not enabled():
         return NullSpan()
     hist = _registry.histogram(name + ".s", **labels)
-    return Span(name, labels, hist, default_journal())
+    return Span(name, labels, hist, trace_args)
 
 
 def snapshot() -> Dict[str, Any]:
-    return _export.snapshot(_registry, _journal)
-
-
-def dump_journal(path: str) -> int:
-    return default_journal().dump_jsonl(path)
+    return _export.snapshot(_registry)
 
 
 diff = _export.diff

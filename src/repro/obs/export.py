@@ -4,8 +4,7 @@ A snapshot is a plain JSON-serializable dict::
 
     {"version": 1, "ts": <time.time()>,
      "counters": {name: int}, "gauges": {name: float},
-     "histograms": {name: {count,sum,min,max,mean,p50,p90,p99,buckets}},
-     "journal": {"len": n, "dropped": n, "capacity": n}}
+     "histograms": {name: {count,sum,min,max,mean,p50,p90,p99,buckets}}}
 
 Two snapshots of the same process diff into *rates*: counter deltas
 divided by the wall-clock gap, histogram count/sum deltas plus the
@@ -17,21 +16,16 @@ run.
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 from repro.obs.metrics import Registry
-from repro.obs.trace import Journal
 
 SNAPSHOT_VERSION = 1
 
 
-def snapshot(registry: Registry,
-             journal: Optional[Journal] = None) -> Dict[str, Any]:
+def snapshot(registry: Registry) -> Dict[str, Any]:
     snap: Dict[str, Any] = {"version": SNAPSHOT_VERSION, "ts": time.time()}
     snap.update(registry.snapshot())
-    if journal is not None:
-        snap["journal"] = {"len": len(journal), "dropped": journal.dropped,
-                           "capacity": journal.capacity}
     return snap
 
 
@@ -58,10 +52,6 @@ def render(snap: Dict[str, Any]) -> str:
             f"histogram {name} count={h['count']} mean={_fmt(h['mean'])} "
             f"p50={_fmt(h['p50'])} p90={_fmt(h['p90'])} "
             f"p99={_fmt(h['p99'])} max={_fmt(h['max'])}")
-    j = snap.get("journal")
-    if j:
-        lines.append(f"journal   len={j['len']} dropped={j['dropped']} "
-                     f"capacity={j['capacity']}")
     return "\n".join(lines)
 
 

@@ -1,87 +1,93 @@
-"""Span tracing: bounded ring-buffer journal + timing context manager.
+"""Span tracing: a timing context manager whose events land in the
+JAX profiler's trace.
 
 ``obs.span("codec.compress", method="hybrid")`` is the one-liner call
-sites use; it times the enclosed block, feeds a duration histogram
-named ``codec.compress.s{method=hybrid}`` and appends one event to the
-process journal.  The journal is a ``collections.deque(maxlen=N)``
-guarded by an ``obs``-ranked lock — O(1) append, oldest events drop
-first, dumpable as JSONL for offline inspection.
+sites use; it times the enclosed block and feeds a duration histogram
+named ``codec.compress.s{method=hybrid}``.  While a profiler trace is
+running (``jax.profiler.start_trace``), the span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so every program
+span sits on the trace's host plane, on the clock the device's
+operations are on.  The profiler keeps the events in memory and writes
+them out at ``stop_trace``.
 
-The disabled-mode twin (:class:`NullSpan`) still reads the clock: spans
-double as the *product's* timing source (``CompactionResult.wall_s``
-comes from ``span.elapsed_s``), so ``duration_s`` must stay correct
-with observability off.  Cost model: two ``perf_counter`` calls per
-span and nothing else — no locks, no journal, no histogram.
+The event's metadata is the span's labels, its ``trace_args`` and the
+calling thread's trace context (:func:`trace_context`), which stamps
+every span opened under it — the ingest queue gives each group commit's
+spans, on the dispatcher and on the writers alike, one ``flush`` id.
+Identifiers go only there: as histogram labels they would make one
+histogram per flush.  An exception leaving the span is recorded as an
+``error`` stat naming its type.
+
+JAX is never imported from here: the annotation is opened only when
+``jax.profiler`` is already loaded, so ``repro.obs`` stays stdlib-only
+to import and CPU-only tools keep working without JAX.
+
+The disabled-mode twin (:class:`NullSpan`) reads the clock and nothing
+else: spans double as the *product's* timing source
+(``CompactionResult.wall_s`` comes from ``span.elapsed_s``), so
+``duration_s`` must stay correct with observability off.
 """
 
 from __future__ import annotations
 
-import json
+import contextlib
+import sys
 import threading
 import time
-from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterator, Optional
 
-from repro.core.locks import make_lock
 from repro.obs.metrics import Histogram
 
+_context = threading.local()
 
-class Journal:
-    """Bounded in-memory event journal (a ring: oldest drop first)."""
 
-    def __init__(self, capacity: int):
-        self.capacity = int(capacity)
-        self._obs_lock = make_lock("obs")
-        self._events: deque = deque(maxlen=max(self.capacity, 1))
-        self._dropped = 0
+@contextlib.contextmanager
+def trace_context(**args: Any) -> Iterator[None]:
+    """Metadata for every span this thread opens inside the block (an
+    inner context adds to, and may override, an outer one)."""
+    outer: Optional[Dict[str, Any]] = getattr(_context, "args", None)
+    _context.args = {**outer, **args} if outer else args
+    try:
+        yield
+    finally:
+        _context.args = outer
 
-    def append(self, event: Dict[str, Any]) -> None:
-        with self._obs_lock:
-            if len(self._events) == self._events.maxlen:
-                self._dropped += 1
-            self._events.append(event)
 
-    def events(self) -> List[Dict[str, Any]]:
-        with self._obs_lock:
-            return list(self._events)
-
-    @property
-    def dropped(self) -> int:
-        with self._obs_lock:
-            return self._dropped
-
-    def __len__(self) -> int:
-        with self._obs_lock:
-            return len(self._events)
-
-    def dump_jsonl(self, path: str) -> int:
-        """Write one JSON object per line; returns the event count."""
-        events = self.events()
-        with open(path, "w", encoding="utf-8") as fh:
-            for ev in events:
-                fh.write(json.dumps(ev, sort_keys=True))
-                fh.write("\n")
-        return len(events)
+def _annotation(name: str, labels: Dict[str, Any],
+                trace_args: Optional[Dict[str, Any]]):
+    """An entered ``TraceAnnotation`` if a profiler trace is running in
+    this process, else None (the profiler would drop the event anyway)."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None or not profiler.TraceAnnotation.is_enabled():
+        return None
+    args = dict(getattr(_context, "args", None) or ())
+    args.update(labels)
+    if trace_args:
+        args.update(trace_args)
+    ann = profiler.TraceAnnotation(name, **args)
+    ann.__enter__()
+    return ann
 
 
 class Span:
-    """Enabled-mode span: times the block, records histogram + journal."""
+    """Enabled-mode span: times the block into its histogram and, while
+    a profiler trace runs, into a trace event."""
 
-    __slots__ = ("name", "labels", "_hist", "_journal", "_t0", "_wall0",
+    __slots__ = ("name", "labels", "trace_args", "_hist", "_ann", "_t0",
                  "duration_s")
 
-    def __init__(self, name: str, labels: Dict[str, Any],
-                 hist: Histogram, journal: Optional[Journal]):
+    def __init__(self, name: str, labels: Dict[str, Any], hist: Histogram,
+                 trace_args: Optional[Dict[str, Any]] = None):
         self.name = name
         self.labels = labels
+        self.trace_args = trace_args
         self._hist = hist
-        self._journal = journal
+        self._ann = None
         self._t0 = 0.0
-        self._wall0 = 0.0
         self.duration_s = 0.0
 
     def __enter__(self) -> "Span":
-        self._wall0 = time.time()
+        self._ann = _annotation(self.name, self.labels, self.trace_args)
         self._t0 = time.perf_counter()
         return self
 
@@ -93,18 +99,11 @@ class Span:
     def __exit__(self, exc_type, exc, tb) -> None:
         self.duration_s = time.perf_counter() - self._t0
         self._hist.observe(self.duration_s)
-        if self._journal is not None:
-            event = {
-                "name": self.name,
-                "ts": self._wall0,
-                "dur_s": self.duration_s,
-                "thread": threading.current_thread().name,
-            }
-            if self.labels:
-                event["labels"] = dict(self.labels)
+        ann, self._ann = self._ann, None
+        if ann is not None:
             if exc_type is not None:
-                event["error"] = exc_type.__name__
-            self._journal.append(event)
+                ann.set_metadata(error=exc_type.__name__)
+            ann.__exit__(exc_type, exc, tb)
 
 
 class NullSpan:

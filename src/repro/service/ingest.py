@@ -106,13 +106,15 @@ class _Submission:
 
 class _Flush:
     """One group commit in flight: `remaining` shard parts still being
-    fsynced, chained to the previous flush for prefix-ordered completion."""
+    fsynced, chained to the previous flush for prefix-ordered completion.
+    `id` numbers the queue's flushes; it stamps the flush's trace events."""
 
-    __slots__ = ("tickets", "remaining", "error", "finished",
+    __slots__ = ("id", "tickets", "remaining", "error", "finished",
                  "prev_finished", "next")
 
-    def __init__(self, tickets: List[IngestTicket], n_parts: int,
-                 prev_finished: bool) -> None:
+    def __init__(self, flush_id: int, tickets: List[IngestTicket],
+                 n_parts: int, prev_finished: bool) -> None:
+        self.id = flush_id
         self.tickets = tickets
         self.remaining = n_parts
         self.error: Optional[BaseException] = None
@@ -145,6 +147,7 @@ class IngestQueue:
         self._pending_texts = 0
         self._dispatching = False
         self._outstanding = 0          # registered, unfinished flushes
+        self._next_flush_id = 0        # dispatcher thread only
         self._tail: Optional[_Flush] = None
         self._flush_requested = False
         self._started = False
@@ -155,7 +158,9 @@ class IngestQueue:
         self._writers: List[threading.Thread] = []
         self._dispatcher: Optional[threading.Thread] = None
         # metrics: registry-backed counters (always real; see repro.obs)
-        # plus queue-depth and submit->durable wait-time histograms
+        # plus queue-depth and wait-time histograms: submit -> durable,
+        # submit -> taken by the dispatcher, and shard part queued ->
+        # its writer starting the commit
         self._n_submitted = obs.owned_counter("ingest.submitted")
         self._n_committed = obs.owned_counter("ingest.committed")
         self._n_flushes = obs.owned_counter("ingest.flushes")
@@ -164,6 +169,8 @@ class IngestQueue:
         self._max_depth = 0
         self._depth_h = obs.histogram("ingest.queue_depth")
         self._wait_h = obs.histogram("ingest.wait.s")
+        self._queue_h = obs.histogram("ingest.queue.s")
+        self._writer_queue_h = obs.histogram("ingest.writer_queue.s")
         obs.owned_gauge("ingest.pending", lambda: self._pending_texts)
 
     # -- lifecycle -------------------------------------------------------------
@@ -287,8 +294,10 @@ class IngestQueue:
                         self._cv.wait()
                 taken: List[_Submission] = []
                 n = 0
+                now = time.monotonic()
                 while self._items and n < self.flush_batch:
                     sub = self._items.popleft()
+                    self._queue_h.observe(now - sub.ticket.submitted_ts)
                     taken.append(sub)
                     n += len(sub.texts)
                 self._pending_texts -= n
@@ -296,9 +305,14 @@ class IngestQueue:
                     self._flush_requested = False
                 self._dispatching = True
                 self._cv.notify_all()  # wake backpressured producers
-            self._plan_and_dispatch(taken)
+            flush_id = self._next_flush_id
+            self._next_flush_id += 1
+            with obs.trace_context(flush=flush_id), \
+                    obs.span("ingest.dispatch", trace_args={"prompts": n}):
+                self._plan_and_dispatch(taken, flush_id)
 
-    def _plan_and_dispatch(self, taken: List[_Submission]) -> None:
+    def _plan_and_dispatch(self, taken: List[_Submission],
+                           flush_id: int) -> None:
         """Plan one flush (compress outside any lock) and hand each shard's
         entries to its writer.  Runs on the dispatcher thread, overlapping
         the previous flush's fsyncs."""
@@ -318,6 +332,7 @@ class IngestQueue:
             parts = {}
         with self._cv:
             flush = _Flush(
+                flush_id,
                 tickets=[sub.ticket for sub in taken],
                 n_parts=len(parts),
                 prev_finished=self._tail is None or self._tail.finished,
@@ -338,7 +353,7 @@ class IngestQueue:
             # (and commit_batch re-routes stale plans itself), so the
             # true shard id travels with the work item
             q = self._writer_queues[shard_id % len(self._writer_queues)]
-            q.put((shard_id, entries, flush))
+            q.put((shard_id, entries, flush, time.monotonic()))
 
     def _maybe_finish(self, flush: Optional[_Flush]) -> None:
         """cv held: cascade prefix-ordered flush completion."""
@@ -366,10 +381,12 @@ class IngestQueue:
             item = q.get()
             if item is None:
                 return
-            shard_id, entries, flush = item
+            shard_id, entries, flush, queued_ts = item
+            self._writer_queue_h.observe(time.monotonic() - queued_ts)
             err: Optional[BaseException] = None
             try:
-                self._store.commit_batch(shard_id, entries)
+                with obs.trace_context(flush=flush.id, shard=shard_id):
+                    self._store.commit_batch(shard_id, entries)
             except BaseException as e:
                 err = e
             with self._cv:

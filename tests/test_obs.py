@@ -1,12 +1,15 @@
 """repro.obs: log2-bucket histogram vs a NumPy oracle, multi-thread
 hammering under the lock sanitizer, disabled-mode no-op identity,
-snapshot/diff round-trips, span journaling, owned-counter stats()
-compatibility, and an end-to-end BatchServer run that must land real
-ms/token samples in the serve histograms."""
+snapshot/diff round-trips, spans in the JAX profiler's trace (with the
+trace context's ids, across the ingest queue's threads), the compile
+listener, owned-counter stats() compatibility, and an end-to-end
+BatchServer run that must land real ms/token samples in the serve
+histograms."""
 
 import dataclasses
 import json
 import threading
+import warnings
 
 import numpy as np
 import pytest
@@ -16,16 +19,38 @@ from repro.obs import export
 from repro.obs.metrics import (EXP_MAX, EXP_MIN, N_BUCKETS, Counter,
                                Histogram, bucket_index, bucket_mid,
                                canonical_name)
-from repro.obs.trace import Journal
 
 
 @pytest.fixture(autouse=True)
 def _fresh_obs(monkeypatch):
-    """Enabled obs against a private registry/journal per test."""
+    """Enabled obs against a private registry per test."""
     monkeypatch.delenv("REPRO_OBS", raising=False)
     obs.reset()
     yield
     obs.reset()
+
+
+def _host_events(trace_dir, fn):
+    """Run ``fn`` under a JAX profiler trace; the trace's host-plane
+    events as ``[(name, {stat: value})]`` in start order."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = sorted(trace_dir.rglob("*.xplane.pb"))
+    data = ProfileData.from_file(str(path))
+    with warnings.catch_warnings():
+        # jaxlib's stats type warns that it has no __module__
+        warnings.simplefilter("ignore", DeprecationWarning)
+        events = [(ev.start_ns, ev.name, dict(ev.stats))
+                  for plane in data.planes if plane.name.startswith("/host:")
+                  for line in plane.lines for ev in line.events]
+    return [(name, stats) for _, name, stats in sorted(events,
+                                                       key=lambda e: e[0])]
 
 
 # ---------------------------------------------------------------------------
@@ -181,6 +206,31 @@ def test_disabled_span_still_times(monkeypatch):
     assert obs.default_registry().names() == []
 
 
+def test_importing_obs_does_not_import_jax():
+    """Spans reach the profiler only through an already-loaded JAX, so
+    CPU-only tools import ``repro.obs`` without it."""
+    import subprocess
+    import sys
+
+    code = ("import sys, repro.obs, repro.obs.trace\n"
+            "with repro.obs.span('no.jax'):\n    pass\n"
+            "sys.exit('jax' in sys.modules)")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+
+
+def test_disabled_span_opens_no_annotation(monkeypatch, tmp_path):
+    monkeypatch.setenv("REPRO_OBS", "0")
+
+    def work():
+        with obs.trace_context(flush=1), obs.span("off.probe"):
+            pass
+
+    names = [name for name, _ in _host_events(tmp_path, work)]
+    assert "off.probe" not in names
+
+
 def test_disabled_owned_counter_still_counts(monkeypatch):
     monkeypatch.setenv("REPRO_OBS", "0")
     c = obs.owned_counter("cache.hits")
@@ -223,41 +273,99 @@ def test_owned_gauge_replace_follows_newest_instance():
 
 
 # ---------------------------------------------------------------------------
-# spans + journal
+# spans in the profiler's trace
 # ---------------------------------------------------------------------------
 
-def test_span_records_histogram_and_journal():
-    with obs.span("unit.op", method="m") as sp:
-        pass
-    assert sp.duration_s >= 0.0
-    snap = obs.snapshot()
-    hs = snap["histograms"]["unit.op.s{method=m}"]
-    assert hs["count"] == 1
-    events = obs.default_journal().events()
-    assert len(events) == 1
-    ev = events[0]
-    assert ev["name"] == "unit.op" and ev["labels"] == {"method": "m"}
-    assert ev["dur_s"] >= 0.0 and "thread" in ev
+def test_span_records_histogram_and_journal(tmp_path):
+    """A span's journal is the profiler's trace: one histogram sample,
+    and one host event named as the span with its labels and
+    ``trace_args`` as stats (which stay out of the histogram's name)."""
+    box = {}
+
+    def work():
+        with obs.span("unit.op", trace_args={"ids": 12}, method="m") as sp:
+            pass
+        box["sp"] = sp
+
+    events = _host_events(tmp_path, work)
+    assert box["sp"].duration_s >= 0.0
+    hists = obs.snapshot()["histograms"]
+    assert [n for n in hists if n.startswith("unit.op")] \
+        == ["unit.op.s{method=m}"]
+    assert hists["unit.op.s{method=m}"]["count"] == 1
+    (stats,) = [st for name, st in events if name == "unit.op"]
+    assert stats == {"method": "m", "ids": 12}
 
 
-def test_span_records_error_type():
-    with pytest.raises(RuntimeError):
-        with obs.span("unit.boom"):
-            raise RuntimeError("nope")
-    ev = obs.default_journal().events()[-1]
-    assert ev["error"] == "RuntimeError"
+def test_span_records_error_type(tmp_path):
+    def work():
+        with pytest.raises(RuntimeError):
+            with obs.span("unit.boom"):
+                raise RuntimeError("nope")
+
+    events = _host_events(tmp_path, work)
+    (stats,) = [st for name, st in events if name == "unit.boom"]
+    assert stats["error"] == "RuntimeError"
+    assert obs.snapshot()["histograms"]["unit.boom.s"]["count"] == 1
 
 
-def test_journal_ring_buffer_drops_oldest(tmp_path):
-    j = Journal(4)
-    for i in range(10):
-        j.append({"name": f"e{i}"})
-    assert len(j) == 4 and j.dropped == 6
-    assert [e["name"] for e in j.events()] == ["e6", "e7", "e8", "e9"]
-    out = tmp_path / "j.jsonl"
-    assert j.dump_jsonl(str(out)) == 4
-    lines = out.read_text().splitlines()
-    assert json.loads(lines[0])["name"] == "e6"
+def test_span_event_carries_trace_context(tmp_path):
+    """The thread's trace context stamps every span under it, nests, and
+    stays on its thread."""
+    def other_thread():
+        with obs.span("ctx.other"):
+            pass
+
+    def work():
+        with obs.trace_context(flush=7):
+            with obs.span("ctx.outer"):
+                with obs.trace_context(shard=2):
+                    with obs.span("ctx.inner"):
+                        pass
+                t = threading.Thread(target=other_thread)
+                t.start()
+                t.join(10)
+                assert not t.is_alive()
+        with obs.span("ctx.after"):
+            pass
+
+    stats = dict(_host_events(tmp_path, work))
+    assert stats["ctx.outer"] == {"flush": 7}
+    assert stats["ctx.inner"] == {"flush": 7, "shard": 2}
+    assert stats["ctx.other"] == {}
+    assert stats["ctx.after"] == {}
+
+
+def test_ingest_flush_spans_share_the_flush_id(tmp_path):
+    """One group commit's spans carry one ``flush`` id on the dispatcher
+    (``ingest.dispatch``, ``store.plan``, the codec's) and on the
+    writers (``store.commit``, with its shard)."""
+    from repro.core.api import PromptCompressor
+    from repro.core.store import ShardedPromptStore
+    from repro.service import IngestQueue
+    from repro.tokenizer.vocab import default_tokenizer
+
+    store = ShardedPromptStore(
+        tmp_path / "store", PromptCompressor(default_tokenizer(),
+                                             method="hybrid"), n_shards=2)
+    texts = [f"flush span probe {i} " * 30 for i in range(6)]
+
+    def work():
+        with IngestQueue(store, flush_batch=3) as q:
+            for i in range(0, 6, 3):
+                q.submit(texts[i:i + 3]).wait(30)
+
+    events = _host_events(tmp_path / "trace", work)
+    store.close()
+    dispatch = [st for name, st in events if name == "ingest.dispatch"]
+    assert [st["flush"] for st in dispatch] == [0, 1]
+    assert all(st["prompts"] == 3 for st in dispatch)
+    for name in ("store.plan", "codec.bpe.encode", "codec.pack.encode"):
+        assert sorted(st["flush"] for n, st in events if n == name) \
+            == [0, 1], name
+    commits = [st for name, st in events if name == "store.commit"]
+    assert commits and {st["flush"] for st in commits} == {0, 1}
+    assert all(st["shard"] in (0, 1) for st in commits)
 
 
 # ---------------------------------------------------------------------------
@@ -296,12 +404,12 @@ def test_snapshot_version_and_shape():
     snap = obs.snapshot()
     assert snap["version"] == export.SNAPSHOT_VERSION
     assert set(snap) >= {"version", "ts", "counters", "gauges", "histograms"}
-    assert "journal" not in snap       # journal is created lazily
     with obs.span("shape.probe"):
         pass
     snap = obs.snapshot()
-    assert snap["journal"]["len"] == 1
-    assert snap["journal"]["capacity"] >= 1
+    assert set(snap) == {"version", "ts", "counters", "gauges", "histograms"}
+    assert snap["histograms"]["shape.probe.s"]["count"] == 1
+    assert "histogram shape.probe.s count=1" in obs.render(snap)
 
 
 # ---------------------------------------------------------------------------
@@ -342,8 +450,68 @@ def test_codec_pipeline_gauges_track_traffic():
     assert snap["counters"]["codec.encode.bytes_in{method=hybrid}"] \
         == sum(len(p) for p in payloads)
     assert snap["gauges"]["codec.compression_ratio{method=hybrid}"] > 1.0
-    assert snap["gauges"]["codec.encode_mb_s{method=hybrid}"] > 0.0
-    assert snap["gauges"]["codec.decode_mb_s{method=hybrid}"] > 0.0
+    # MB = 10**6 bytes, the paper's unit
+    enc_s = snap["histograms"]["codec.encode.s{method=hybrid}"]
+    assert snap["gauges"]["codec.encode_mb_s{method=hybrid}"] \
+        == pytest.approx(sum(len(p) for p in payloads) / 1e6
+                         / (enc_s["mean"] * enc_s["count"]))
+    dec_s = snap["histograms"]["codec.decode.s{method=hybrid}"]
+    assert snap["gauges"]["codec.decode_mb_s{method=hybrid}"] \
+        == pytest.approx(sum(len(p) for p in payloads) / 1e6
+                         / (dec_s["mean"] * dec_s["count"]))
+
+
+def test_token_pack_stage_splits_into_bpe_and_pack_spans():
+    """``codec.bpe.encode`` and ``codec.pack.encode`` each take one
+    sample per batch and together lie inside the stage's own time."""
+    from repro.core.codec import TokenPackCodec
+    from repro.tokenizer.vocab import default_tokenizer
+
+    codec = TokenPackCodec(default_tokenizer())
+    for i in range(3):
+        codec.encode_batch([(f"split the stage {i} " * 50).encode()] * 2)
+    h = obs.snapshot()["histograms"]
+    stage = h["codec.encode.s{scheme=fixed,stage=token-pack}"]
+    bpe, pack = h["codec.bpe.encode.s"], h["codec.pack.encode.s"]
+    assert stage["count"] == bpe["count"] == pack["count"] == 3
+    assert bpe["sum"] + pack["sum"] <= stage["sum"]
+
+
+def test_pack_kernel_span_carries_each_launch_ids(tmp_path):
+    """``kernel.token_pack`` opens once per width-group launch, with the
+    group's real id count and width as stats."""
+    from repro.kernels.token_pack import pack_fixed_batch_device
+
+    ids = [np.arange(300, dtype=np.uint32), np.arange(50, dtype=np.uint32),
+           np.array([70_000, 1], dtype=np.uint32)]
+    events = _host_events(tmp_path,
+                          lambda: pack_fixed_batch_device(ids, interpret=True))
+    launches = sorted((st["width"], st["ids"])
+                      for name, st in events if name == "kernel.token_pack")
+    assert launches == [(2, 350), (4, 2)]
+    assert obs.snapshot()["histograms"]["kernel.token_pack.s"]["count"] == 2
+
+
+def test_fresh_compile_lands_in_device_compile_histogram():
+    """From the first device routing decision on, a backend compile adds
+    one sample under its jitted function's name."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import device
+
+    assert device.use_device(1, "REPRO_PACK_DEVICE_MIN", 0, force=True)
+
+    def obs_compile_probe(x):
+        return x * 3 + 1
+
+    f = jax.jit(obs_compile_probe)
+    name = "device.compile.s{fn=jit(obs_compile_probe)}"
+    assert name not in obs.snapshot()["histograms"]
+    f(jnp.arange(5)).block_until_ready()
+    f(jnp.arange(5)).block_until_ready()          # cached: no compile
+    h = obs.snapshot()["histograms"][name]
+    assert h["count"] == 1 and h["sum"] > 0.0
 
 
 def test_serve_loop_ms_per_token_histograms():

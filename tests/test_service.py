@@ -9,6 +9,7 @@ import time
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core.api import PromptCompressor
 from repro.core.store import ShardedPromptStore, content_key
 from repro.service import (BackgroundCompactor, IngestError, IngestQueue,
@@ -105,6 +106,33 @@ def test_ingest_queue_matches_sync_store_bytes(tmp_path, tok):
         name = f"shard-{i:03d}.bin"
         assert (tmp_path / "a" / name).read_bytes() == \
             (tmp_path / "b" / name).read_bytes()
+
+
+def test_ingest_wait_histograms_sample_each_submission_and_part(
+        tmp_path, tok, monkeypatch):
+    """``ingest.queue.s`` takes one sample per submission, when the
+    dispatcher takes it; ``ingest.writer_queue.s`` one per shard part,
+    when its writer starts the commit; ``ingest.dispatch.s`` one per
+    flush."""
+    monkeypatch.delenv("REPRO_OBS", raising=False)
+    obs.reset()
+    try:
+        store = _store(tmp_path, tok)
+        texts = _texts(12, tag="waits")
+        with IngestQueue(store, flush_batch=4, flush_interval_s=0.01) as q:
+            for i in range(0, 12, 2):
+                q.submit(texts[i:i + 2])
+            q.drain()
+            flushes = q.stats()["flushes"]
+        h = obs.snapshot()["histograms"]
+        assert h["ingest.queue.s"]["count"] == 6
+        assert h["ingest.dispatch.s"]["count"] == flushes
+        parts = h["store.commit.s"]["count"]   # one commit per shard part
+        assert flushes <= parts <= 4 * flushes
+        assert h["ingest.writer_queue.s"]["count"] == parts
+        assert h["ingest.writer_queue.s"]["min"] >= 0.0
+    finally:
+        obs.reset()
 
 
 def test_ingest_interval_flush_without_explicit_flush(tmp_path, tok):
