@@ -3,6 +3,10 @@
 `pack_tokens_device(ids)` reproduces LoPace's fixed-width packing decision
 (Eq. 7: uint16 iff max(ids) <= 65535) and returns (format_byte, bytes) —
 bit-identical to repro.core.packing.pack_fixed, validated in tests.
+
+Every launch is zero-padded on the host to ``size_bucket(n, 2048)`` ids,
+so the jitted kernels compile per size bucket (at most eight shapes per
+octave per width), not per exact id count; the pad is sliced away.
 """
 
 from __future__ import annotations
@@ -15,10 +19,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.kernels import interpret_default
+from repro.kernels import interpret_default, size_bucket
 from repro.kernels.token_pack.kernel import delta_zigzag_kernel, pack_tokens_kernel
 
 _BLOCK = 2048
+
+
+def _bucket_buffer(n: int) -> np.ndarray:
+    """Zero-filled int32 launch buffer for ``n`` ids, ``size_bucket`` long
+    (always a whole number of kernel blocks)."""
+    return np.zeros(size_bucket(n, _BLOCK), np.int32)
 
 
 @partial(jax.jit, static_argnames=("width", "interpret"))
@@ -38,8 +48,9 @@ def pack_tokens_device(ids, interpret: Optional[bool] = None
     if ids.size == 0:
         return 0x00, b""
     width = 2 if int(ids.max()) <= 0xFFFF else 4
-    out = _pack_padded(jnp.asarray(ids, jnp.int32), width,
-                       interpret_default(interpret))
+    buf = _bucket_buffer(ids.size)
+    buf[: ids.size] = ids
+    out = _pack_padded(jnp.asarray(buf), width, interpret_default(interpret))
     return (0x00 if width == 2 else 0x01), np.asarray(out)[: ids.size].tobytes()
 
 
@@ -48,11 +59,11 @@ def pack_fixed_batch_device(ids_list, interpret: Optional[bool] = None
     """Batch fixed-width packing: the vectorized device path of the codec layer.
 
     Streams are grouped by packing width (Eq. 7 decides per stream), each
-    group is concatenated into one [N] id vector, streamed through the
-    Pallas byte-split kernel in a single launch, and the [N, k] byte plane
-    is sliced back per stream.  Bit-identical to
-    ``repro.core.packing.pack_fixed`` applied per stream (format byte
-    included), which the kernel parity tests assert.
+    group is concatenated into one [N] id vector, zero-padded to its size
+    bucket, streamed through the Pallas byte-split kernel in a single
+    launch, and the [N, k] byte plane is sliced back per stream.
+    Bit-identical to ``repro.core.packing.pack_fixed`` applied per stream
+    (format byte included), which the kernel parity tests assert.
     """
     interpret = interpret_default(interpret)
     arrs = [np.asarray(ids, dtype=np.uint32) for ids in ids_list]
@@ -67,14 +78,17 @@ def pack_fixed_batch_device(ids_list, interpret: Optional[bool] = None
         if not members:
             continue
         fmt = 0x00 if width == 2 else 0x01
-        concat = np.concatenate([arrs[i] for i in members])
+        n = sum(arrs[i].size for i in members)
+        buf = _bucket_buffer(n)
+        np.concatenate([arrs[i] for i in members], out=buf[:n],
+                       casting="unsafe")
         # one launch per width group, read back to the host; its trace
-        # event carries the group's real (unpadded) id count
+        # event carries the group's real id count and the padded length
         with obs.span("kernel.token_pack",
-                      trace_args={"ids": int(concat.size), "width": width}):
+                      trace_args={"ids": n, "padded": buf.size,
+                                  "width": width}):
             plane = np.asarray(
-                _pack_padded(jnp.asarray(concat, jnp.int32), width, interpret)
-            )[: concat.size]
+                _pack_padded(jnp.asarray(buf), width, interpret))[:n]
         offsets = np.cumsum([0] + [arrs[i].size for i in members])
         for j, i in enumerate(members):
             out[i] = bytes([fmt]) + plane[offsets[j]:offsets[j + 1]].tobytes()
@@ -107,17 +121,16 @@ def unpack_fixed_device(payload) -> jnp.ndarray:
 def delta_zigzag_device(ids: jnp.ndarray,
                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """[N] ids -> [N,4] zigzag-delta bytes (feeder for the rANS stage)."""
-    return _delta_zigzag(ids, interpret_default(interpret))
+    host = np.asarray(ids)
+    n = host.shape[0]
+    buf = _bucket_buffer(n)
+    buf[:n] = host
+    return _delta_zigzag(jnp.asarray(buf), interpret_default(interpret))[:n]
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def _delta_zigzag(ids: jnp.ndarray, interpret: bool) -> jnp.ndarray:
+    """``ids``: an int32 launch buffer from ``_bucket_buffer``."""
     prev = jnp.concatenate([jnp.zeros(1, ids.dtype), ids[:-1]])
-    n = ids.shape[0]
-    pad = (-n) % min(_BLOCK, max(n, 1))
-    idsp = jnp.pad(ids, (0, pad))
-    prevp = jnp.pad(prev, (0, pad))
-    out = delta_zigzag_kernel(idsp.astype(jnp.int32), prevp.astype(jnp.int32),
-                              width=4, block_n=min(_BLOCK, idsp.shape[0]),
-                              interpret=interpret)
-    return out[:n]
+    return delta_zigzag_kernel(ids, prev, width=4, block_n=_BLOCK,
+                               interpret=interpret)

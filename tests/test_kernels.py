@@ -8,11 +8,13 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core.packing import pack_tokens
+from repro.kernels import size_bucket
 from repro.kernels.flash_attention import attention_ref, flash_attention
 from repro.kernels.histogram import histogram_ref, token_histogram
 from repro.kernels.token_pack import (delta_zigzag_device, delta_zigzag_ref,
                                       pack_fixed_batch_device, pack_ref,
                                       pack_tokens_device)
+from repro.kernels.token_pack import ops as pack_ops
 
 RNG = np.random.default_rng(0)
 
@@ -66,7 +68,8 @@ def test_flash_attention_matches_model_engine():
 # -- token pack --------------------------------------------------------------
 
 @pytest.mark.parametrize("n,hi", [(1, 60000), (777, 60000), (2048, 60000),
-                                  (4096, 100000), (3000, 2**31 - 1)])
+                                  (4096, 100000), (3000, 2**31 - 1),
+                                  (2049, 60000), (16385, 100000)])
 def test_pack_kernel_bit_identical(n, hi):
     ids = RNG.integers(0, hi, n)
     fb, data = pack_tokens_device(ids)
@@ -104,6 +107,41 @@ def test_pack_batch_kernel_property(streams):
     assert got == [pack_tokens(a, "fixed") for a in arrs]
 
 
+def _streams_of(total, hi, rng):
+    """1-5 streams of ids below ``hi`` whose lengths sum to ``total``."""
+    cuts = np.sort(rng.choice(np.arange(1, total), min(rng.integers(0, 5),
+                                                      total - 1), replace=False))
+    return [rng.integers(0, hi, k).astype(np.uint32)
+            for k in np.diff(np.concatenate([[0], cuts, [total]]))]
+
+
+# the last is a bucket length plus one
+@pytest.mark.parametrize("total", [2047, 2048, 2049, 16383, 16385,
+                                   size_bucket(20000, 2048) + 1])
+def test_pack_batch_kernel_bucket_edges(total):
+    """Group totals at the edges of the size buckets the launches are
+    padded to, beside a width-4 group: the pad never reaches a frame."""
+    rng = np.random.default_rng(total)
+    streams = _streams_of(total, 65536, rng) + [
+        rng.integers(0, 2**31 - 1, 3).astype(np.uint32)]
+    got = pack_fixed_batch_device(streams, interpret=True)
+    assert got == [pack_tokens(ids, "fixed") for ids in streams]
+
+
+def test_pack_batch_compiles_per_bucket_not_per_total():
+    """40 group commits whose width-2 totals all differ but share one
+    octave compile the pack kernel for at most eight shapes, and every
+    frame still equals per-stream ``pack_fixed``."""
+    rng = np.random.default_rng(15)
+    totals = rng.choice(np.arange(16385, 32768), 40, replace=False)
+    before = pack_ops._pack_padded._cache_size()
+    for total in totals:
+        streams = _streams_of(int(total), 65536, rng)
+        got = pack_fixed_batch_device(streams, interpret=True)
+        assert got == [pack_tokens(ids, "fixed") for ids in streams]
+    assert pack_ops._pack_padded._cache_size() - before <= 8
+
+
 def test_pack_ref_widths():
     ids = jnp.asarray([0, 1, 255, 256, 65535], jnp.int32)
     b2 = pack_ref(ids, 2)
@@ -113,6 +151,15 @@ def test_pack_ref_widths():
 
 def test_delta_zigzag_kernel():
     ids = jnp.asarray(RNG.integers(0, 2**30, 3000), jnp.int32)
+    prev = jnp.concatenate([jnp.zeros(1, ids.dtype), ids[:-1]])
+    np.testing.assert_array_equal(np.asarray(delta_zigzag_device(ids)),
+                                  np.asarray(delta_zigzag_ref(ids, prev)))
+
+
+@pytest.mark.parametrize("n", [1, 2047, 2049])
+def test_delta_zigzag_kernel_bucket_edges(n):
+    """The zero pad up to the size bucket never leaks into the deltas."""
+    ids = jnp.asarray(RNG.integers(0, 2**30, n), jnp.int32)
     prev = jnp.concatenate([jnp.zeros(1, ids.dtype), ids[:-1]])
     np.testing.assert_array_equal(np.asarray(delta_zigzag_device(ids)),
                                   np.asarray(delta_zigzag_ref(ids, prev)))

@@ -479,16 +479,18 @@ def test_token_pack_stage_splits_into_bpe_and_pack_spans():
 
 def test_pack_kernel_span_carries_each_launch_ids(tmp_path):
     """``kernel.token_pack`` opens once per width-group launch, with the
-    group's real id count and width as stats."""
+    group's real id count, its padded launch length and width as stats."""
+    from repro.kernels import size_bucket
     from repro.kernels.token_pack import pack_fixed_batch_device
 
     ids = [np.arange(300, dtype=np.uint32), np.arange(50, dtype=np.uint32),
            np.array([70_000, 1], dtype=np.uint32)]
     events = _host_events(tmp_path,
                           lambda: pack_fixed_batch_device(ids, interpret=True))
-    launches = sorted((st["width"], st["ids"])
+    launches = sorted((st["width"], st["ids"], st["padded"])
                       for name, st in events if name == "kernel.token_pack")
-    assert launches == [(2, 350), (4, 2)]
+    assert [(w, n) for w, n, _ in launches] == [(2, 350), (4, 2)]
+    assert all(p == size_bucket(n, 2048) for _, n, p in launches)
     assert obs.snapshot()["histograms"]["kernel.token_pack.s"]["count"] == 2
 
 
