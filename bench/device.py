@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 
 class NoAccelerator(RuntimeError):
@@ -61,27 +61,32 @@ class CompileClock:
         import jax.monitoring
 
         self._lock = threading.Lock()
-        self._spans: List[Tuple[float, float, bool]] = []
+        self._spans: List[Tuple[float, float, bool, Optional[str]]] = []
         jax.monitoring.register_event_duration_secs_listener(self._event)
 
-    def _event(self, name: str, secs: float, **_) -> None:
+    def _event(self, name: str, secs: float, fun_name: Optional[str] = None,
+               **_) -> None:
         if name.startswith("/jax/core/compile/"):
             end = time.monotonic()
             with self._lock:
-                self._spans.append(
-                    (end - secs, end, name.endswith("backend_compile_duration")))
+                backend = name.endswith("backend_compile_duration")
+                self._spans.append((end - secs, end, backend, fun_name))
 
     def window(self, t0: float, t1: float) -> dict:
-        """Backend compiles that ended in [t0, t1), and the wall time in
-        that span covered by at least one compile event."""
+        """Backend compiles that ended in [t0, t1), in all and by the
+        function compiled, and the wall time in that span covered by at
+        least one compile event."""
         with self._lock:
-            spans = sorted((max(a, t0), min(b, t1), c)
-                           for a, b, c in self._spans if b > t0 and a < t1)
+            spans = sorted((max(a, t0), min(b, t1))
+                           for a, b, _, _ in self._spans if b > t0 and a < t1)
+            fns = [f for _, b, c, f in self._spans if c and t0 <= b < t1]
         covered, reach = 0.0, t0
-        for a, b, _ in spans:
+        for a, b in spans:
             if b > reach:
                 covered += b - max(a, reach)
                 reach = b
-        with self._lock:
-            n = sum(1 for _, b, c in self._spans if c and t0 <= b < t1)
-        return {"backend_compiles": n, "compile_s": covered}
+        by_fn: dict = {}
+        for f in fns:
+            by_fn[str(f)] = by_fn.get(str(f), 0) + 1
+        return {"backend_compiles": len(fns), "compile_s": covered,
+                "backend_compiles_by_fn": by_fn}

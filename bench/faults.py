@@ -13,6 +13,8 @@ before any put, and returns a function that takes it out again.
              and acknowledges all of them.
 ``token_put`` one token id of every record altered where the codec
              produces it, before packing.
+``token_get`` one token id of every record altered on the read path,
+             where the codec unpacks it.
 ``no_fsync`` the store's fsyncs left out: every write flushed to the
              operating system, none made durable.
 ``late_fsync`` each fsync made 0.2 s after the store asked for it, on a
@@ -81,6 +83,23 @@ def token_put() -> Callable[[], None]:
     return _patch(TokenPackCodec, "encode_ids_batch", make)
 
 
+def token_get() -> Callable[[], None]:
+    import numpy as np
+
+    from repro.core.codec import TokenPackCodec
+
+    def make(orig):
+        def decode_ids_batch(self, payloads, to_device=False):
+            out = [np.array(a, np.uint32)
+                   for a in orig(self, payloads, to_device=to_device)]
+            for a in out:
+                if a.size:
+                    a[a.size // 2] ^= 1
+            return out
+        return decode_ids_batch
+    return _patch(TokenPackCodec, "decode_ids_batch", make)
+
+
 def no_fsync() -> Callable[[], None]:
     import repro.core.store as store
 
@@ -114,7 +133,8 @@ def late_fsync() -> Callable[[], None]:
 
 
 FAULTS = {"pack16": pack16, "no_write": no_write, "half": half,
-          "token_put": token_put, "no_fsync": no_fsync,
+          "token_put": token_put, "token_get": token_get,
+          "no_fsync": no_fsync,
           "late_fsync": late_fsync}
 
 

@@ -3,10 +3,13 @@
 The run holds the chip in this one process.  It trains the vocabulary,
 starts the load generators (child processes that never import JAX),
 serves a fresh store through the program's ``PromptService`` and an
-in-process writer gateway, warms up on the cell's own traffic, and
-measures ``seconds`` of it.  After the window it drains the service,
-reads the device's memory peak, frees the program's state and compares
-the answers with the plain reference:
+in-process writer gateway, as ``deploy.py`` builds them from the
+configuration, runs the traffic kind's ``setup`` hook if it has one
+(preloading or warming through the gateway, inside set-up), warms up on
+the cell's own traffic, and measures ``seconds`` of it.  After the
+window it drains the service, reads the device's memory peak, frees the
+program's state and compares the answers with the plain reference the
+configuration names.  Every kind is held to these checks:
 
 * every request answered (none lost), and none answered with an error
   other than an admission reject (rejects count as failed requests);
@@ -18,6 +21,9 @@ the answers with the plain reference:
   its fsyncs made durable, and reads back as its text (its content key
   is the SHA-256 of the text); a sample drawn from the seed, the longest
   put among it, reads back as the reference's token ids.
+
+A kind's ``check`` hook adds its own exact counts beside them (the
+answers to its reads, say), each with the limit 0.
 
 End-to-end metrics (``--trace 0``) and per-layer metrics (``--trace 1``)
 are each read by ``bench/metrics/<name>.py`` from what the run gathered.
@@ -176,12 +182,9 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool,
 
 def _run(cell, seed, seconds, trace, t_process, dev, peaks, work, clock,
          gens, faults, require_chip, keep, marks) -> dict:
+    import deploy
     import faults as fault_lib
     from repro import obs
-    from repro.core.api import PromptCompressor
-    from repro.core.store import ShardedPromptStore
-    from repro.service import PromptService
-    from repro.service.gateway import start_in_thread
     from repro.tokenizer.vocab import default_tokenizer
 
     cfg, mix = cell.config, cell.traffic
@@ -190,36 +193,36 @@ def _run(cell, seed, seconds, trace, t_process, dev, peaks, work, clock,
     marks["vocabulary"] = time.monotonic()
 
     def compressor():
-        return PromptCompressor(tok, method=cfg["method"],
-                                backend=cfg["backend"], level=cfg["level"])
+        return deploy.compressor(cfg, tok)
 
     root = work / "store"
     fsyncs = durable.Fsyncs().install()
     try:
-        store = ShardedPromptStore(root, compressor(), n_shards=cfg["shards"])
+        store = deploy.store(cfg, root, compressor())
     except BaseException:
         fsyncs.remove()
         raise
     undo = [fault_lib.apply(name) for name in faults]
 
     spans = _TraceSpans(BENCH / "trace_spans.json") if trace else None
-    service = PromptService(store, cache_bytes=cfg["cache_bytes"],
-                            flush_batch=cfg["flush_batch"],
-                            flush_interval_s=cfg["flush_interval_s"])
+    service = deploy.service(cfg, store)
     service.start()
-    gw = start_in_thread(service, max_inflight=cfg["gateway_max_inflight"],
-                         conn_window=cfg["gateway_conn_window"])
+    gw = deploy.gateway(cfg, service)
     try:
         for g in gens:
             g.expect("ready", 600)
         marks["generators"] = time.monotonic()
         disk0 = _disk_bytes(root)
+        prepared = {"records": [], "go": {}}
+        if hasattr(kind, "setup"):
+            prepared = kind.setup(mix, cfg, seed, seconds, gw.port)
+            marks["kind_setup"] = time.monotonic()
         t_start = time.monotonic() + START_LEAD_S
         t0 = t_start + mix["warmup_s"]
         t1 = t0 + seconds
         for g in gens:
-            g.go({"port": gw.port, "t_start": t_start,
-                  "t0": t0, "t1": t1, "grace_s": GRACE_S})
+            g.go(dict(prepared["go"], port=gw.port, t_start=t_start,
+                      t0=t0, t1=t1, grace_s=GRACE_S))
         tracer = _Tracer(work / "trace") if trace else None
         if tracer:
             tracer.start()
@@ -249,7 +252,8 @@ def _run(cell, seed, seconds, trace, t_process, dev, peaks, work, clock,
             u()
     del service, store, gw
 
-    records = [r for res in results for r in res["records"]]
+    records = prepared["records"] + [r for res in results
+                                     for r in res["records"]]
     if keep is not None:
         keep.update(records=records, t0=t0, t1=t1)
     ctx = SimpleNamespace(
@@ -311,6 +315,8 @@ def _notes(ctx, marks: dict, t_process: float, results: list) -> dict:
         "setup_marks_s": {k: v - t_process for k, v in marks.items()},
         "check_s": ctx.check_s,
         "backend_compiles_in_window": ctx.compiles["backend_compiles"],
+        "backend_compiles_in_window_by_fn":
+            ctx.compiles["backend_compiles_by_fn"],
         "compile_s_in_window": ctx.compiles["compile_s"],
         "bodies_reused": sum(res.get("reused", 0) for res in results),
     }
@@ -319,12 +325,15 @@ def _notes(ctx, marks: dict, t_process: float, results: list) -> dict:
         recs = [r for r in ctx.window if r["op"] == op]
         lat = [stats.latency_ms(ctx, r) for r in recs]
         notes[f"{op}_n"] = len(lat)
-        notes[f"{op}_p50_ms"] = stats.nearest_rank(lat, 0.5)
-        notes[f"{op}_p95_ms"] = stats.nearest_rank(lat, 0.95)
+        for q in (50, 90, 95, 99):
+            notes[f"{op}_p{q}_ms"] = stats.nearest_rank(lat, q / 100)
         for label, part in (("first", [r for r in recs if r["due"] < half]),
                             ("second", [r for r in recs if r["due"] >= half])):
             notes[f"{op}_p95_ms_{label}_half"] = stats.nearest_rank(
                 [stats.latency_ms(ctx, r) for r in part], 0.95)
+    late = [(r["sent"] - r["due"]) * 1e3 for r in ctx.window]
+    notes["generator_late_ms_p99"] = stats.nearest_rank(late, 0.99)
+    notes["generator_late_ms_max"] = max(late, default=None)
     notes["answered_ok"] = sum(r["status"] == "ok" for r in ctx.window)
     notes["answered_ok_by_close_1s"] = sum(
         r["status"] == "ok" and r["done"] <= ctx.t1 + 1.0 for r in ctx.window)
@@ -340,12 +349,12 @@ def _kernel_patterns() -> Dict[str, str]:
 
 
 def _check(ctx, kind, root, compressor, fsyncs) -> dict:
-    """Every number compared, each beside its limit (all exact: 0)."""
+    """Every number compared, each beside its limit (all exact: 0): the
+    checks of acknowledged puts that every kind is held to, then those
+    of the kind's ``check`` hook."""
     import numpy as np
 
-    import corpus
-    from reference import hybrid as ref
-    from repro.core.store import ShardedPromptStore
+    import deploy
 
     cfg, mix = ctx.cell.config, ctx.cell.traffic
     recs = ctx.records
@@ -359,7 +368,7 @@ def _check(ctx, kind, root, compressor, fsyncs) -> dict:
             root, [(r["done"], r["keys"]) for r in acked]),
     }
     fsyncs.crash(root)
-    reopened = ShardedPromptStore(root, compressor(), readonly=True)
+    reopened = deploy.store(cfg, root, compressor(), readonly=True)
     stored = set(reopened.keys())
     keys = [k for r in acked for k in r["keys"]]
     checks["acked_missing"] = sum(k not in stored for k in keys)
@@ -369,12 +378,7 @@ def _check(ctx, kind, root, compressor, fsyncs) -> dict:
     readers = _Readback(cfg, root, [k for k in keys if k in stored],
                         root.parent)
     try:
-        vocab = cfg["vocabulary"]
-        merges = ref.train(
-            (p.text for p in corpus.generate_corpus(vocab["train_prompts"],
-                                                    seed=vocab["train_seed"])),
-            vocab["size"])
-        enc = ref.Encoder(merges, vocab["specials"])
+        enc = spec.reference_module(cfg).encoder(cfg)
 
         idents = [(tuple(i), k) for r in acked
                   for i, k in zip(r["ids"], r["keys"])]
@@ -403,6 +407,8 @@ def _check(ctx, kind, root, compressor, fsyncs) -> dict:
           f"at its ack and read back as text from the store reopened after "
           f"a cut to its fsynced bytes; {len(pick)} of them read back as "
           f"token ids", file=sys.stderr, flush=True)
+    if hasattr(kind, "check"):
+        checks.update(kind.check(ctx, enc))
     return {name: {"value": int(v), "limit": 0} for name, v in checks.items()}
 
 

@@ -5,9 +5,9 @@
 
 Started by the harness, never imports JAX or the program.  It speaks
 JSON lines: on standard output ``{"ready": true}`` once its requests are
-made; on standard input it waits for ``{"go": {...}}`` (port and times),
-drives the mix, and answers ``{"result": {...}}`` with one record per
-request.
+made; on standard input it waits for ``{"go": {...}}`` (port, times, and
+what the kind's ``setup`` hook hands the generators), drives the mix,
+and answers ``{"result": {...}}`` with one record per request.
 """
 
 from __future__ import annotations

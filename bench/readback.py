@@ -5,7 +5,8 @@
 
 Started by the harness after the window, several at once, each on its
 share of the keys (every N-th from the I-th); JAX runs on the CPU here,
-the chip stays with the harness.  Opens the store read-only and prints
+the chip stays with the harness.  Opens the store read-only, as
+``deploy.py`` builds it from the configuration, and prints
 one JSON line, ``{"read": n, "bad": m}``: ``m`` counts the keys whose
 text fails to read or does not hash to the key (a content key is the
 SHA-256 of its text).
@@ -34,14 +35,13 @@ def main() -> int:
     args = ap.parse_args()
     cfg = json.loads(args.config)
 
-    from repro.core.api import PromptCompressor
-    from repro.core.store import ShardedPromptStore
+    import deploy
     from repro.tokenizer.vocab import default_tokenizer
 
     keys = Path(args.keys).read_text().split()[args.part::args.parts]
-    compressor = PromptCompressor(default_tokenizer(), method=cfg["method"],
-                                  backend=cfg["backend"], level=cfg["level"])
-    store = ShardedPromptStore(args.root, compressor, readonly=True)
+    store = deploy.store(cfg, args.root,
+                         deploy.compressor(cfg, default_tokenizer()),
+                         readonly=True)
 
     def text_of(key: str):
         try:

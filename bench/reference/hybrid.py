@@ -14,6 +14,11 @@ made enters the comparison:
 * encoding maps each special marker to its id (100000 + its position in
   the list) and, within a word, merges the pair of lowest rank first,
   every non-overlapping occurrence from the left, until none applies.
+
+A configuration names its reference (``"reference"``, default
+``hybrid``); the harness loads ``bench/reference/<name>.py`` and calls
+``encoder(config)``, which every reference module offers: an object
+whose ``encode(text)`` gives the text's token ids as a list of ints.
 """
 
 from __future__ import annotations
@@ -85,6 +90,17 @@ def train(docs: Iterable[str], vocab_size: int) -> List[Tuple[int, int]]:
                 counts[p] += n * freq[w]
                 holders.setdefault(p, set()).add(w)
     return merges
+
+
+def encoder(config: dict) -> "Encoder":
+    """The reference's encoder for a configuration: trained on the
+    corpus its ``vocabulary`` names, with its special markers."""
+    import corpus
+
+    vocab = config["vocabulary"]
+    merges = train((p.text for p in corpus.generate_corpus(
+        vocab["train_prompts"], seed=vocab["train_seed"])), vocab["size"])
+    return Encoder(merges, vocab["specials"])
 
 
 class Encoder:

@@ -1,10 +1,31 @@
 """Finds everything a cell needs by the names in ``BENCHMARK.json``.
 
-A cell names a configuration and a traffic mix; the configuration is
-``bench/configs/<config>.json``, the mix ``bench/traffic/<traffic>.json``,
-whose ``kind`` names its generator ``bench/traffic/kinds/<kind>.py``, and
-each per-layer metric is read by ``bench/metrics/<metric>.py``.  A new
-cell or metric is a new file and a new entry, never an edit.
+A cell names a configuration and a traffic mix, and each of its metrics
+is read by a file of its own.  A new cell, configuration, mix, kind,
+reference or metric is a new file and a new entry, never an edit:
+
+* the configuration is ``bench/configs/<config>.json``.  ``deploy.py``
+  builds the program's compressor, store, service and gateway from its
+  keys, and its optional ``"program"`` block passes further keyword
+  arguments to those constructors by the program's own parameter names;
+* the configuration's ``"reference"`` (default ``hybrid``) names its
+  plain reference ``bench/reference/<name>.py``, which offers
+  ``encoder(config)``: an object whose ``encode(text)`` gives the
+  reference's token ids of a text;
+* the mix is ``bench/traffic/<traffic>.json``, whose ``kind`` names its
+  generator ``bench/traffic/kinds/<kind>.py``.  A kind offers
+  ``prepare`` and ``drive`` (run in each load-generator process) and
+  ``put_text`` and ``put_chars`` (a put's text and size made again from
+  its ident, for the check).  It may offer two hooks, run in the
+  harness's process: ``setup(params, config, seed, seconds, port)``,
+  after the gateway starts and before the generators are released,
+  through the gateway's own ops and inside ``setup_s``, which returns
+  ``{"records": [...], "go": {...}}``: the requests it made (checked as
+  any other) and fields for the generators' ``go``; and
+  ``check(ctx, encoder)``, after the window, which returns further exact
+  counts, each compared with the limit 0 beside the harness's own checks
+  of acknowledged puts;
+* each metric is read by ``bench/metrics/<metric>.py``.
 """
 
 from __future__ import annotations
@@ -51,6 +72,11 @@ def traffic(name: str) -> dict:
 
 def kind_module(kind: str) -> ModuleType:
     return load_module(BENCH / "traffic" / "kinds" / f"{kind}.py")
+
+
+def reference_module(config: dict) -> ModuleType:
+    return load_module(BENCH / "reference"
+                       / f"{config.get('reference', 'hybrid')}.py")
 
 
 def metric_module(name: str) -> ModuleType:
