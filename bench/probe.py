@@ -3,8 +3,8 @@
     python3 bench/probe.py --workload <cell> --seed N --seconds S
                            [--fault pack16] [--set traffic.batch=8]
 
-For the control (``--fault pack16``) and the planted faults (see
-``faults.py``), which have to come out not correct, and for rehearsals
+For the control (``--fault pack16``) and the planted faults (each a file
+in ``bench/faults/``), which have to come out not correct, and for rehearsals
 off the chip at a tiny size (``--no-chip``, ``--set``).  The benchmark's
 own runs never use it.
 Prints the result line as ``run.py`` does, and on standard error the

@@ -24,7 +24,14 @@ reference or metric is a new file and a new entry, never an edit:
   any other) and fields for the generators' ``go``; and
   ``check(ctx, encoder)``, after the window, which returns further exact
   counts, each compared with the limit 0 beside the harness's own checks
-  of acknowledged puts;
+  of acknowledged puts.  A kind also declares what the benchmark's tests
+  need of it: ``REHEARSAL``, the traffic overrides that make it a tiny run
+  off the chip; ``CHECKS``, the names its ``check`` hook returns, in order
+  (``[]`` without one); and ``FAULTS``, each fault it must be able to show
+  beyond those every kind is held to, mapped to the check that has to read
+  above 0 under it;
+* a fault is ``bench/faults/<fault>.py``, whose ``apply()`` patches the
+  program and returns what takes the patch out again;
 * each metric is read by ``bench/metrics/<metric>.py``.
 """
 
@@ -81,6 +88,10 @@ def reference_module(config: dict) -> ModuleType:
 
 def metric_module(name: str) -> ModuleType:
     return load_module(BENCH / "metrics" / f"{name}.py")
+
+
+def fault_module(name: str) -> ModuleType:
+    return load_module(BENCH / "faults" / f"{name}.py")
 
 
 def _applies(metric: dict, cell: str) -> bool:

@@ -9,6 +9,9 @@ it out to the clients in an order of its own, so seeds differ in order,
 not in the bytes they put.  A client cycles through its share of the pool, and a first
 line naming client and sequence number makes every put distinct from
 every earlier one.
+
+The kind has no hooks: it is held to the checks of acknowledged puts and
+the faults every kind can show, and adds none of its own.
 """
 
 from __future__ import annotations
@@ -16,7 +19,7 @@ from __future__ import annotations
 import asyncio
 import functools
 import time
-from typing import List
+from typing import Dict, List
 
 import numpy as np
 
@@ -25,6 +28,12 @@ import wire
 
 STREAM = 1          # corpus stream of the backfill bodies
 BODIES = 0          # the seed of the bodies' content, the same for every run
+
+#: a tiny run off the chip, for the benchmark's tests
+REHEARSAL = {"pool_per_client": 4, "warmup_s": 1.0, "readback_sample": 6}
+#: the names ``check`` returns (it has none), and the faults of its own
+CHECKS: List[str] = []
+FAULTS: Dict[str, str] = {}
 
 
 @functools.lru_cache(maxsize=4)

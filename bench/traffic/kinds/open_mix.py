@@ -33,7 +33,9 @@ stream, the same for every seed.
 ``check`` compares every ``get_tokens`` answer with the reference's
 token ids of its key's text, by digest, once per distinct key
 (``get_tokens_bad``), and counts answers for keys that no acknowledged
-put wrote (``get_unknown_key``).
+put wrote (``get_unknown_key``).  Beyond the faults every kind can show,
+the kind reads, so it must show ``token_get``, an id altered on the read
+path, in ``get_tokens_bad``.
 """
 
 from __future__ import annotations
@@ -54,6 +56,14 @@ RECORDS = 2         # corpus stream of the preloaded records
 UPDATES = 3         # corpus stream of the update bodies
 DRAWS = 4           # fixed stream of the Zipfian draws
 SCHEDULE = 5        # the seed's stream that orders the work
+
+#: a tiny run off the chip, for the benchmark's tests
+REHEARSAL = {"records": 48, "rate_per_s": 20, "warm_min_chars": 20000,
+             "warmup_s": 1.0, "readback_sample": 6}
+#: the names ``check`` returns, in order, and the faults of its own, each
+#: with the check that has to read above 0 under it
+CHECKS = ["get_tokens_bad", "get_unknown_key"]
+FAULTS = {"token_get": "get_tokens_bad"}
 
 # YCSB's ScrambledZipfianGenerator: ITEM_COUNT, ZIPFIAN_CONSTANT and ZETAN,
 # its precomputed zeta(ITEM_COUNT, 0.99)
