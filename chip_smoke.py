@@ -254,6 +254,8 @@ def kernel_proofs(tok, text) -> dict:
     buf = np.zeros(p, np.uint8)
     buf[:len(packed)] = np.frombuffer(packed, np.uint8)
     f32 = jnp.asarray(freqs, jnp.uint32)
+    grid, rec, ends, tables = rans_ops._lay_out([(sym, freqs, lanes)],
+                                                lanes == 1)
     stages = {
         "token_pack": (pack_ops._pack_padded, (jnp.asarray(ids), 2, False)),
         "histogram": (hist_ops._token_histogram,
@@ -262,7 +264,8 @@ def kernel_proofs(tok, text) -> dict:
                      (jnp.asarray(buf), jnp.int32(len(packed)),
                       jnp.int32(0), p, False)),
         "rans_encode": (rans_ops._encode_stage,
-                        (jnp.asarray(sym), f32, lanes, pb, False)),
+                        (jnp.asarray(grid), jnp.asarray(rec),
+                         jnp.asarray(ends), jnp.asarray(tables), pb, False)),
         "rans_decode": (rans_ops._decode_stage,
                         (jnp.asarray(words, jnp.uint16),
                          jnp.asarray(states, jnp.uint32), f32, int(sym.size),

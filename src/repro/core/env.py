@@ -176,7 +176,8 @@ declare("REPRO_RANS_LANES", _parse_lanes, None,
 declare("REPRO_LZ_DEVICE_MIN", _parse_int_min0, None,
         "payload bytes before the LZ77 device match finder pays off")
 declare("REPRO_RANS_DEVICE_MIN", _parse_int_min0, None,
-        "payload bytes before the device rANS lane kernels pay off")
+        "payload bytes (a byte-stage batch's total) before the device "
+        "rANS lane kernels pay off")
 declare("REPRO_PACK_DEVICE_MIN", _parse_int_min0, None,
         "batch token count before the device pack kernel pays off")
 declare("REPRO_HIST_DEVICE_MIN", _parse_int_min0, None,

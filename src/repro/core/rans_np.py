@@ -40,10 +40,11 @@ parse multi-lane blobs; this reader accepts every layout.
 from __future__ import annotations
 
 import struct
-from typing import Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import env
 
 PROB_BITS_DEFAULT = 12
@@ -340,6 +341,62 @@ def rans_compress_bytes(data: bytes, prob_bits: int = PROB_BITS_DEFAULT,
                          lanes.bit_length() - 1, asize)
     return (header + table + states.astype("<u4").tobytes()
             + struct.pack("<I", words.size) + words.astype("<u2").tobytes())
+
+
+def _lanes_blob(n: int, prob_bits: int, table: bytes, asize: int,
+                lanes: int, words: np.ndarray, states: np.ndarray) -> bytes:
+    """The blob ``rans_compress_bytes`` writes for `n` symbols coded over
+    `lanes` lanes (`words` in forward/decode order)."""
+    body = words.astype("<u2").tobytes()
+    if lanes == 1:
+        return (struct.pack("<IBH", n, prob_bits, asize) + table
+                + struct.pack("<II", int(states[0]), words.size) + body)
+    return (struct.pack("<IBBH", n, prob_bits | 0x80, lanes.bit_length() - 1,
+                        asize)
+            + table + states.astype("<u4").tobytes()
+            + struct.pack("<I", words.size) + body)
+
+
+def rans_compress_batch(datas: Sequence[bytes],
+                        prob_bits: int = PROB_BITS_DEFAULT) -> List[bytes]:
+    """``[rans_compress_bytes(d) for d in datas]``, byte for byte, with the
+    batch as the unit of device work.  When the device route is taken
+    for the batch's total bytes (the ``_use_device_rans`` policy, here
+    applied to the whole batch), every record's lanes are encoded in at
+    most two kernel launches (``rans_encode_records_device``: one for the
+    single-lane records, one for the multi-lane ones) and the host
+    assembles each blob; empty records and the single-symbol alphabet
+    stay on the NumPy coder.  Each record is counted in
+    ``codec.rans.records{path=device|host}``."""
+    if not _use_device_rans(sum(map(len, datas))):
+        obs.counter("codec.rans.records", path="host").inc(len(datas))
+        return [rans_compress_bytes(d, prob_bits) for d in datas]
+    blobs: List[Optional[bytes]] = [None] * len(datas)
+    jobs = []
+    for i, data in enumerate(datas):
+        symbols = np.frombuffer(data, dtype=np.uint8)
+        if symbols.size:
+            lanes = _env_lanes()
+            if lanes is None:
+                lanes = _auto_lanes(symbols.size)
+            freqs, table, asize = _freq_table(symbols, prob_bits)
+            if asize > 1:
+                jobs.append((i, symbols, freqs, table, asize, lanes))
+                continue
+        blobs[i] = rans_compress_bytes(data, prob_bits)
+    if jobs:
+        from repro.kernels.rans_lanes import rans_encode_records_device
+
+        coded = rans_encode_records_device(
+            [(sym, freqs, lanes) for _, sym, freqs, _, _, lanes in jobs],
+            prob_bits)
+        for (i, sym, _, table, asize, lanes), (words, states) in zip(
+                jobs, coded):
+            blobs[i] = _lanes_blob(sym.size, prob_bits, table, asize, lanes,
+                                   words, states)
+    obs.counter("codec.rans.records", path="device").inc(len(jobs))
+    obs.counter("codec.rans.records", path="host").inc(len(datas) - len(jobs))
+    return blobs
 
 
 def _read_freq_table(blob: bytes, asize: int, off: int) -> Tuple[np.ndarray, int]:

@@ -18,7 +18,9 @@ from the refs (Mosaic has no gather from a loaded value).
 * encode walks step blocks in **reverse** grid order (rANS encodes
   back-to-front), carrying the lane states in an output ref whose block
   index_map is constant — the classic Pallas sequential-reduction
-  pattern the histogram kernel uses;
+  pattern the histogram kernel uses.  Each lane has its own step end,
+  so the lanes of many records (each with its own length, lane count
+  and partial tail row) encode side by side in one launch;
 * decode walks forward, carrying the lane states in the same way and
   the word cursor in SMEM.  Everything that the NumPy coder does with
   a 1-D gather is recast as dense compares and small matmuls, which is
@@ -47,9 +49,9 @@ frequency is below ``2**prob_bits`` — the single-symbol-alphabet edge
 (f == 2**prob_bits) is routed to the NumPy uint64 path by the dispatch
 layer, never to this kernel.
 
-Step blocks are padded to ``block_t`` multiples; padded rows are masked
-out of the state evolution (and emit nothing), so padding never touches
-the stream.
+Step blocks are padded to ``block_t`` multiples; rows at or past a
+lane's end (encode) or past ``total_t`` (decode) are masked out of the
+state evolution and emit nothing, so padding never touches the stream.
 """
 
 from __future__ import annotations
@@ -65,16 +67,17 @@ DEFAULT_BLOCK_T = 256   # lockstep steps per grid block
 WORD_ROW = 128          # words per row of the decoder's word ref
 
 
-def _encode_kernel(x0_ref, fs_ref, cs_ref, words_ref, emit_ref, state_ref, *,
-                   block_t: int, total_t: int, prob_bits: int):
+def _encode_kernel(ends_ref, fc_ref, out_ref, state_ref, *, block_t: int,
+                   prob_bits: int):
     i = pl.program_id(0)
     nb = pl.num_programs(0)
 
     @pl.when(i == 0)
     def _init():
-        state_ref[...] = x0_ref[...]
+        state_ref[...] = jnp.full(state_ref.shape, 1 << 16, jnp.uint32)
 
     base = (nb - 1 - i) * block_t            # reverse block order
+    ends = ends_ref[...]                     # [1, lanes] i32
     shift = jnp.uint32(32 - prob_bits)
     pb = jnp.uint32(prob_bits)
     lo16 = jnp.uint32(0xFFFF)
@@ -82,12 +85,13 @@ def _encode_kernel(x0_ref, fs_ref, cs_ref, words_ref, emit_ref, state_ref, *,
 
     def row(t, x):                           # x: [1, lanes] u32
         r = block_t - 1 - t                  # reverse rows within the block
-        valid = base + r < total_t
-        f = fs_ref[pl.ds(r, 1), :]
-        c = cs_ref[pl.ds(r, 1), :]
+        valid = base + r < ends
+        fc = fc_ref[pl.ds(r, 1), :]
+        f = fc & lo16
+        c = fc >> sixteen
         em = (x >= (f << shift)) & valid
-        words_ref[pl.ds(r, 1), :] = x & lo16
-        emit_ref[pl.ds(r, 1), :] = em.astype(jnp.int32)
+        out_ref[pl.ds(r, 1), :] = (x & lo16) | (em.astype(jnp.uint32)
+                                                << sixteen)
         x2 = jnp.where(em, x >> sixteen, x)
         xn = ((x2 // f) << pb) + (x2 % f) + c
         return jnp.where(valid, xn, x)
@@ -95,40 +99,41 @@ def _encode_kernel(x0_ref, fs_ref, cs_ref, words_ref, emit_ref, state_ref, *,
     state_ref[...] = jax.lax.fori_loop(0, block_t, row, state_ref[...])
 
 
-def rans_encode_lanes_kernel(fs: jnp.ndarray, cs: jnp.ndarray,
-                             x0: jnp.ndarray, *, total_t: int,
+def rans_encode_lanes_kernel(fc: jnp.ndarray, ends: jnp.ndarray, *,
                              prob_bits: int,
                              block_t: int = DEFAULT_BLOCK_T,
                              interpret: bool = False):
-    """fs/cs: [Tp, lanes] u32 per-step (freq, cumfreq), Tp a block_t
-    multiple covering total_t real steps; x0: [1, lanes] u32 initial
-    states (the host runs the partial tail step first — rANS encodes it
-    first).
+    """fc: [Tp, lanes] u32 per-step ``freq | cumfreq << 16`` (both below
+    ``2**prob_bits``, freq >= 1 everywhere, padding included), Tp a
+    block_t multiple; ends: [1, lanes] i32, the rows each lane steps
+    through (lane ``j`` encodes rows ``ends[j] - 1`` down to 0; 0 for a
+    padding lane).
 
-    Returns (words [Tp, lanes] u32 dense, emit [Tp, lanes] i32 mask,
-    states [1, lanes] u32).  Forward stream = words[emit] in row-major
-    order; padded rows never emit.
+    Returns (out [Tp, lanes] u32: the word a cell may emit in bits 0-15
+    and its emit flag in bit 16; states [1, lanes] u32).  Forward stream
+    of one lane block = the words of the flagged cells in row-major
+    order; rows at or past a lane's end never emit.
     """
-    tp, lanes = fs.shape
+    tp, lanes = fc.shape
     if tp % block_t:
         raise ValueError("pad T to a block multiple upstream")
     nb = tp // block_t
     kernel = functools.partial(_encode_kernel, block_t=block_t,
-                               total_t=total_t, prob_bits=prob_bits)
+                               prob_bits=prob_bits)
     rows = pl.BlockSpec((block_t, lanes), lambda i, nb=nb: (nb - 1 - i, 0))
     state = pl.BlockSpec((1, lanes), lambda i: (0, 0))
     return pl.pallas_call(
         kernel,
         grid=(nb,),
-        in_specs=[state, rows, rows],
-        out_specs=[rows, rows, state],
+        in_specs=[state, rows],
+        out_specs=[rows, state],
         out_shape=[
             jax.ShapeDtypeStruct((tp, lanes), jnp.uint32),
-            jax.ShapeDtypeStruct((tp, lanes), jnp.int32),
             jax.ShapeDtypeStruct((1, lanes), jnp.uint32),
         ],
         interpret=interpret,
-    )(x0, fs, cs)
+        name="rans_lanes_encode",
+    )(ends, fc)
 
 
 def decode_window_rows(lanes_padded: int) -> int:

@@ -8,11 +8,18 @@ tests/test_kernel_codec.py).
 
 Split of labor:
 
-* the jitted stage functions run the frequency-table gathers, the
-  partial tail step (rANS encodes it first / decodes it last — one
-  vector op), padding, and the Pallas lockstep kernel on device;
-* the host side only compacts the dense [T, lanes] word/mask pair into
-  the serialized stream (encode) and runs the underflow check (decode).
+* encode takes many records at once (``rans_encode_records_device``):
+  the host lays their symbols out as one u8 grid, each record's lanes
+  a block of columns with its partial tail step as one more row on the
+  lanes it covers, pads the grid to its ``launch_shape`` and uploads
+  it with one frequency table per record; the jitted stage gathers
+  each cell's (freq, cumfreq) on the device and runs the Pallas
+  lockstep kernel, every lane up to its own step end; the host reads
+  back one u32 per cell (word and emit flag) and compacts each
+  record's block into its stream.  A single record is a batch of one;
+* decode's jitted stage runs the frequency-table prefix, padding, the
+  Pallas kernel and the partial tail step (decoded last) on device;
+  the host runs the underflow check.
   Decode can skip the host entirely: ``to_host=False`` returns the
   symbol array still resident in device memory — the serve path's
   decompress-to-tokens feeds on this.
@@ -25,55 +32,145 @@ single-symbol alphabet here: ``f == 2**prob_bits`` makes
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import interpret_default
+from repro import obs
+from repro.kernels import interpret_default, size_bucket
 from repro.kernels.rans_lanes.kernel import (DEFAULT_BLOCK_T, WORD_ROW,
                                              decode_window_rows,
                                              rans_decode_lanes_kernel,
                                              rans_encode_lanes_kernel)
 
 
-@partial(jax.jit, static_argnames=("lanes", "prob_bits", "interpret"))
-def _encode_stage(symbols: jnp.ndarray, freqs: jnp.ndarray, lanes: int,
-                  prob_bits: int, interpret: bool):
-    n = symbols.shape[0]
-    T = n // lanes
-    rem = n - T * lanes
-    f32 = freqs.astype(jnp.uint32)
-    cum = jnp.cumsum(f32, dtype=jnp.uint32) - f32      # exclusive prefix
-    sym = symbols.astype(jnp.int32)
-    fs_all = f32[sym]
-    cs_all = cum[sym]
-    shift = jnp.uint32(32 - prob_bits)
-    pb = jnp.uint32(prob_bits)
-    x0 = jnp.full((lanes,), 1 << 16, jnp.uint32)
-    tail_w = jnp.zeros((lanes,), jnp.uint32)
-    tail_em = jnp.zeros((lanes,), jnp.int32)
-    if rem:   # tail step runs first on the encode side
-        ft = fs_all[T * lanes:]
-        ct = cs_all[T * lanes:]
-        xa = x0[:rem]
-        em = xa >= (ft << shift)
-        tail_w = tail_w.at[:rem].set(xa & jnp.uint32(0xFFFF))
-        tail_em = tail_em.at[:rem].set(em.astype(jnp.int32))
-        xa = jnp.where(em, xa >> jnp.uint32(16), xa)
-        xa = ((xa // ft) << pb) + (xa % ft) + ct
-        x0 = x0.at[:rem].set(xa)
-    bt = DEFAULT_BLOCK_T
-    tp = max(-(-T // bt) * bt, bt)
-    fs = jnp.pad(fs_all[: T * lanes].reshape(T, lanes),
-                 ((0, tp - T), (0, 0)), constant_values=1)
-    cs = jnp.pad(cs_all[: T * lanes].reshape(T, lanes),
-                 ((0, tp - T), (0, 0)))
-    words, emit, states = rans_encode_lanes_kernel(
-        fs, cs, x0[None, :], total_t=T, prob_bits=prob_bits, block_t=bt,
-        interpret=interpret)
-    return words, emit, states[0], tail_w, tail_em
+#: launch-shape floors per lane layout.  Auto lane counts give a
+#: multi-lane record 256-512 steps (its tail row included) and a
+#: single-lane one fewer than 4,096, and a group commit of the corpus
+#: law holds about 1,100 lanes of at most 64 records: so every such
+#: flush launches one [512, 2048] and one [4096, 128] grid with tables
+#: for 64 records, and larger groups step up by ``size_bucket``
+_MULTI_ROWS, _MULTI_LANES = 512, 2048
+_SINGLE_ROWS, _SINGLE_LANES = 4096, 128
+_RECORDS = 64
+_BLOCK_CELLS = 1 << 18      # cells of one step block held in VMEM
+
+
+def launch_shape(rows: int, lanes: int, records: int,
+                 single: bool) -> Tuple[int, int, int]:
+    """(steps, lanes, table rows) a launch of ``records`` records needing
+    ``rows`` steps and ``lanes`` lanes in all is padded to, each axis by
+    ``size_bucket``, so that the kernel compiles per bucket, not per
+    flush."""
+    row_floor, lane_floor = ((_SINGLE_ROWS, _SINGLE_LANES) if single
+                             else (_MULTI_ROWS, _MULTI_LANES))
+    return (size_bucket(rows, row_floor), size_bucket(lanes, lane_floor),
+            size_bucket(records, _RECORDS))
+
+
+@partial(jax.jit, static_argnames=("prob_bits", "interpret"))
+def _encode_stage(grid: jnp.ndarray, rec: jnp.ndarray, ends: jnp.ndarray,
+                  freqs: jnp.ndarray, prob_bits: int, interpret: bool):
+    """grid: [Tp, Lp] u8 symbols (record-major lane blocks, step ``t`` of
+    a lane in row ``t``); rec / ends: [1, Lp] i32, each lane's record
+    and step end; freqs: [Rp, 256] u32, one table per record.  The
+    tables' gathers run here, on the device."""
+    f = freqs.astype(jnp.uint32)
+    cum = jnp.cumsum(f, axis=1, dtype=jnp.uint32) - f   # exclusive prefix
+    idx = rec * 256 + grid.astype(jnp.int32)
+    fs = jnp.maximum(f.reshape(-1)[idx], jnp.uint32(1))  # pad cells: f = 1
+    cs = cum.reshape(-1)[idx]
+    lp = grid.shape[1]
+    block_t = min(DEFAULT_BLOCK_T,
+                  1 << ((_BLOCK_CELLS // lp).bit_length() - 1))
+    return rans_encode_lanes_kernel(
+        fs | (cs << jnp.uint32(16)), ends, prob_bits=prob_bits,
+        block_t=block_t, interpret=interpret)
+
+
+def _lay_out(group, single: bool):
+    """Host inputs of one launch over ``group``, (symbols, freqs, lanes)
+    triples all single-lane or all multi-lane: the symbol grid, each
+    lane's record and step end, the tables, padded to their
+    ``launch_shape``."""
+    steps = [divmod(sym.size, lanes) for sym, _, lanes in group]
+    rows = max(t + (rem > 0) for t, rem in steps)
+    cols = sum(lanes for _, _, lanes in group)
+    tp, lp, rp = launch_shape(rows, cols, len(group), single)
+    grid = np.zeros((tp, lp), np.uint8)
+    rec = np.zeros((1, lp), np.int32)
+    ends = np.zeros((1, lp), np.int32)        # padding lanes never step
+    tables = np.zeros((rp, 256), np.uint32)
+    off = 0
+    for k, ((sym, freqs, lanes), (t, rem)) in enumerate(zip(group, steps)):
+        grid[:t, off:off + lanes] = sym[: t * lanes].reshape(t, lanes)
+        ends[0, off:off + lanes] = t
+        if rem:   # the partial tail: one more row on lanes < rem
+            grid[t, off:off + rem] = sym[t * lanes:]
+            ends[0, off:off + rem] = t + 1
+        rec[0, off:off + lanes] = k
+        tables[k, : freqs.size] = freqs
+        off += lanes
+    return grid, rec, ends, tables
+
+
+def _encode_group(group, single: bool, prob_bits: int,
+                  interpret: bool) -> List[Tuple[np.ndarray, np.ndarray]]:
+    """One launch over ``group`` (as ``_lay_out`` takes it)."""
+    grid, rec, ends, tables = _lay_out(group, single)
+    # one launch and its read-back; the trace event carries the group's
+    # real sizes (the launch is their ``launch_shape``)
+    with obs.span("kernel.rans_lanes",
+                  trace_args={"records": len(group),
+                              "lanes": sum(g[2] for g in group),
+                              "steps": int(ends.max())}):
+        cells_d, states_d = _encode_stage(
+            jnp.asarray(grid), jnp.asarray(rec), jnp.asarray(ends),
+            jnp.asarray(tables), prob_bits, interpret)
+        cells = np.asarray(cells_d)
+        states = np.asarray(states_d)[0]
+    out = []
+    off = 0
+    for sym, _, lanes in group:
+        block = cells[: -(-sym.size // lanes), off:off + lanes]
+        # forward stream: flagged words in row-major (step, lane) order
+        words = block[(block >> 16).astype(bool)].astype(np.uint16)
+        out.append((words, states[off:off + lanes].copy()))
+        off += lanes
+    return out
+
+
+def rans_encode_records_device(
+        records: Sequence[Tuple[np.ndarray, np.ndarray, int]],
+        prob_bits: int, interpret: Optional[bool] = None,
+) -> List[Tuple[np.ndarray, np.ndarray]]:
+    """Device counterpart of ``rans_np.rans_encode_interleaved`` over many
+    records at once.  ``records`` holds (symbols u8, freqs [256], lanes)
+    triples, every frequency below ``2**prob_bits``; returns, per record,
+    (words u16 in forward/decode order, final states [lanes] u32),
+    bit-identical to the NumPy coder (and, for one lane, to the scalar
+    ``rans_encode`` stream stored reversed).
+
+    Single-lane records are the columns of one launch, multi-lane
+    records' lanes lie side by side in another: at most two launches,
+    whatever the number of records."""
+    interpret = interpret_default(interpret)
+    for sym, freqs, lanes in records:
+        if int(np.max(freqs)) >= 1 << prob_bits:
+            raise ValueError("a single-symbol alphabet needs the NumPy "
+                             "coder's uint64 lanes")
+    out: list = [None] * len(records)
+    for single in (True, False):
+        members = [j for j, r in enumerate(records)
+                   if (r[2] == 1) == single]
+        if members:
+            coded = _encode_group([records[j] for j in members], single,
+                                  prob_bits, interpret)
+            for j, result in zip(members, coded):
+                out[j] = result
+    return out
 
 
 def rans_encode_interleaved_device(
@@ -82,21 +179,10 @@ def rans_encode_interleaved_device(
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Device counterpart of ``rans_np.rans_encode_interleaved``: returns
     (words u16 in forward/decode order, final states [lanes] u32),
-    bit-identical to the NumPy coder."""
-    interpret = interpret_default(interpret)
-    n = int(symbols.size)
-    words_d, emit_d, states_d, tail_w, tail_em = _encode_stage(
-        jnp.asarray(symbols, jnp.uint8), jnp.asarray(freqs, jnp.uint32),
-        int(lanes), int(prob_bits), interpret)
-    # forward stream = dense words masked in row-major (step asc, lane
-    # asc) order; the tail step's words (emitted first) come last
-    emit = np.asarray(emit_d, dtype=bool)
-    fwd = np.asarray(words_d)[emit].astype(np.uint16)
-    rem = n - (n // lanes) * lanes
-    if rem:
-        te = np.asarray(tail_em, dtype=bool)
-        fwd = np.concatenate([fwd, np.asarray(tail_w)[te].astype(np.uint16)])
-    return fwd, np.asarray(states_d, np.uint32)
+    bit-identical to the NumPy coder.  A batch of one record."""
+    return rans_encode_records_device(
+        [(np.asarray(symbols, np.uint8), np.asarray(freqs), int(lanes))],
+        prob_bits, interpret)[0]
 
 
 @partial(jax.jit, static_argnames=("n", "lanes", "prob_bits", "interpret"))

@@ -25,8 +25,8 @@ from repro.kernels.token_pack import ops as pack_ops
 PACK_IDS = 266_240       # a 40-prompt group commit of the paper corpus
 HIST_BYTES = 64 << 10
 PROMPT_MEDIAN, PROMPT_MAX = 20_803, 213_379   # paper §4.1, characters
-# rANS record lengths whose auto lane count is 16 and 1024
-RANS_N = {16: 6_000, 1024: 300_000}
+# rANS record lengths whose auto lane count is 1, 16 and 1024
+RANS_N = {1: 4_000, 16: 6_000, 1024: 300_000}
 
 
 @pytest.fixture(scope="module")
@@ -77,17 +77,25 @@ def test_lz_candidates_compile(one_chip, chars):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("lanes", sorted(RANS_N))
-def test_rans_encode_compiles(one_chip, lanes):
+@pytest.mark.parametrize("lanes,records", [(1, 64), (16, 1), (1024, 1),
+                                           (1024, 4)])
+def test_rans_encode_compiles(one_chip, lanes, records):
+    """The launch of ``records`` records of this lane count: a flush's
+    single-lane columns, a batch of one, and a group wider than the
+    lane floor."""
     n = RANS_N[lanes]
     assert _auto_lanes(n) == lanes
+    t, rem = divmod(n, lanes)
+    tp, lp, rp = rans_ops.launch_shape(t + (rem > 0), lanes * records,
+                                       records, lanes == 1)
     text = _compiled_text(one_chip, rans_ops._encode_stage,
-                          ((n,), jnp.uint8), ((256,), jnp.uint32),
-                          lanes, 12, False)
+                          ((tp, lp), jnp.uint8), ((1, lp), jnp.int32),
+                          ((1, lp), jnp.int32), ((rp, 256), jnp.uint32),
+                          12, False)
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("lanes", sorted(RANS_N))
+@pytest.mark.parametrize("lanes", [16, 1024])
 def test_rans_decode_compiles(one_chip, lanes):
     n = RANS_N[lanes]
     text = _compiled_text(one_chip, rans_ops._decode_stage,
